@@ -1,9 +1,2 @@
 """repro — JAX/Pallas reproduction of "Fast OLAP Query Execution in Main
-Memory on Large Data in a Cluster".
-
-Importing any submodule installs the JAX version-compat shims (see
-``repro.compat``) so the code runs on both current and 0.4.x JAX APIs.
-"""
-from repro import compat as _compat
-
-_compat.install()
+Memory on Large Data in a Cluster"."""

@@ -15,20 +15,83 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+# f32 contractions at full precision: the TPU's default rounds f32 MXU
+# inputs to bf16, which moves sums of low-cardinality measures (Q1's
+# discount and tax) by ~1e-3 relative, far outside the oracle tolerance
+EXACT = lax.Precision.HIGHEST
+
+# Rows per MXU contraction.  A contraction accumulates its rows in f32 in
+# order; over a whole 60M-row partition that moved Q1's sums by ~3e-3
+# relative on a TPU v5e, so rows are contracted in blocks and the block
+# sums added.  Wide operands take shorter blocks (about 16 MB of operand).
+SUM_BLOCK_ROWS = 1 << 17
+
+
+def _block_sum(n: int, width: int, partial):
+    """Sum over row blocks of ``partial(start, size, keep) -> (width,)``.
+    The last block is clamped to end at ``n``; ``keep`` masks off its rows
+    that the previous block already summed."""
+    size = min(n, SUM_BLOCK_ROWS, max(1024, (1 << 22) // max(width, 1)))
+
+    def step(i, acc):
+        start = jnp.minimum(i * size, n - size)
+        keep = (start + jnp.arange(size)) >= i * size
+        return acc + partial(start, size, keep)
+
+    return lax.fori_loop(0, -(-n // size), step,
+                         jnp.zeros(width, jnp.float32))
+
+
+def _rows(x, start, size):
+    return lax.dynamic_slice_in_dim(x, start, size)
+
 
 def group_sum_onehot(values, group_ids, num_groups: int, mask=None):
-    """sum(values) per group via one-hot matmul: (G, n) @ (n, c) on the MXU.
+    """sum(values) per group via one-hot matmuls: (G, rows) @ (rows, c)
+    on the MXU, one row block at a time.
 
     values: (n,) or (n, c) — c aggregates share one pass.
     Returns (G,) or (G, c) f32.
     """
     v = values if values.ndim == 2 else values[:, None]
-    v = v.astype(jnp.float32)
-    if mask is not None:
-        v = jnp.where(mask[:, None], v, 0.0)
-    onehot = (group_ids[None, :] == jnp.arange(num_groups, dtype=group_ids.dtype)[:, None])
-    out = jnp.dot(onehot.astype(jnp.float32), v, preferred_element_type=jnp.float32)
+    n, c = v.shape
+    groups = jnp.arange(num_groups, dtype=group_ids.dtype)[:, None]
+
+    def partial(start, size, keep):
+        if mask is not None:
+            keep = keep & _rows(mask, start, size)
+        vb = jnp.where(keep[:, None], _rows(v, start, size), 0.0)
+        onehot = (_rows(group_ids, start, size)[None, :] == groups)
+        return jnp.dot(onehot.astype(jnp.float32), vb.astype(jnp.float32),
+                       preferred_element_type=jnp.float32,
+                       precision=EXACT).reshape(-1)
+
+    out = _block_sum(n, num_groups * c, partial).reshape(num_groups, c)
     return out if values.ndim == 2 else out[:, 0]
+
+
+def group_sum_maskgemm(values, group_ids, num_groups: int, mask=None):
+    """sum(values) per group as ``mask @ (onehot (x) values)``: (G, c) f32.
+
+    The batched lowering's form: group codes and values are
+    parameter-independent and only ``mask`` varies per lane, so under
+    ``vmap`` each row block is ONE ``(B, rows) x (rows, G*c)`` GEMM over
+    the lane masks.  Out-of-range codes match no one-hot column and drop
+    out."""
+    n, c = values.shape
+    groups = jnp.arange(num_groups, dtype=jnp.int32)
+
+    def partial(start, size, keep):
+        if mask is not None:
+            keep = keep & _rows(mask, start, size)
+        onehot = (_rows(group_ids, start, size)[:, None] == groups)
+        v = _rows(values, start, size).astype(jnp.float32)
+        expanded = (onehot.astype(jnp.float32)[:, :, None] * v[:, None, :]
+                    ).reshape(size, num_groups * c)
+        return jnp.dot(keep.astype(jnp.float32), expanded,
+                       preferred_element_type=jnp.float32, precision=EXACT)
+
+    return _block_sum(n, num_groups * c, partial).reshape(num_groups, c)
 
 
 def group_count(group_ids, num_groups: int, mask=None):

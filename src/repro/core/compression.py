@@ -18,6 +18,7 @@ semi-join alternatives (information-theoretic bits communicated).
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 
 # ---------------------------------------------------------------------------
@@ -101,11 +102,34 @@ def gather_bits(words, idx, width: int):
 
 
 def unpack_bits(words, n: int, width: int):
-    """Inverse of pack_bits; returns uint32 array of length n."""
+    """Inverse of pack_bits; returns uint32 array of length n.
+
+    Gather-free: 32 consecutive values occupy exactly ``width`` words, so
+    value ``j`` of every 32-value group sits at a static word and bit
+    offset — one strided word column per ``j``, shifted and masked.  (A
+    gather per value compiles to code that grows with ``n`` on TPU.)"""
     assert 0 <= width <= 32
     if width == 0:
         return jnp.zeros(n, jnp.uint32)
-    return gather_bits(words, jnp.arange(n, dtype=jnp.uint32), width)
+    groups = -(-n // 32)
+    need = groups * width
+    if words.shape[0] < need:
+        words = jnp.pad(words, (0, need - words.shape[0]))
+    mask = _width_mask(width)
+
+    def column(wi):  # word wi of every group: a strided 1-D slice
+        return lax.slice(words, (wi,), (wi + (groups - 1) * width + 1,),
+                         (width,))
+
+    vals = []
+    for j in range(32):
+        bit = j * width
+        wi, off = bit >> 5, bit & 31
+        v = column(wi) >> jnp.uint32(off)
+        if off + width > 32:  # static straddle into the next word
+            v = v | (column(wi + 1) << jnp.uint32(32 - off))
+        vals.append(v & mask)
+    return jnp.stack(vals).T.reshape(-1)[:n]
 
 
 def required_width(max_val: int) -> int:

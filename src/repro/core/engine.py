@@ -13,9 +13,9 @@ import dataclasses
 from typing import Callable, Mapping
 
 import jax
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.columnar import Table, decode_columns, shard_table
 from repro.core.exchange import WireFormat
 from repro.core.partitioning import RangePartitioning
@@ -54,20 +54,30 @@ class PlanContext:
         return self.wires.get(name, WireFormat.raw())
 
 
+# Largest partition (rows per node) whose batched plans vmap their lanes.
+# A vmapped plan holds lanes x rows arrays (per-lane filter masks), which
+# past this size outgrow one chip's memory; larger partitions run the lanes
+# one after another inside the same dispatch.
+BATCH_VMAP_MAX_ROWS = 1 << 22
+
+
+def vmaps_lanes(rows_per_node: int) -> bool:
+    """Whether a batched plan over partitions of ``rows_per_node`` rows
+    vmaps its lanes (else it loops over them)."""
+    return rows_per_node <= BATCH_VMAP_MAX_ROWS
+
+
 class Cluster:
     """A shared-nothing cluster on a 1-D device mesh."""
 
     def __init__(self, devices=None, axis: str = "nodes"):
         devices = list(devices if devices is not None else jax.devices())
         self.axis = axis
-        axis_types = getattr(jax.sharding, "AxisType", None)
-        self.mesh = compat.make_mesh(
-            (len(devices),),
-            (axis,),
-            axis_types=(axis_types.Auto,) if axis_types is not None else None,
-            devices=devices,
-        )
+        self.mesh = jax.make_mesh((len(devices),), (axis,),
+                                  axis_types=(jax.sharding.AxisType.Auto,),
+                                  devices=devices)
         self.num_nodes = len(devices)
+        self.device_kind = devices[0].device_kind
 
     # -- data placement ----------------------------------------------------
     def load(self, table: Table) -> Table:
@@ -108,7 +118,9 @@ class Cluster:
         a leading batch axis and the plan body is ``vmap``-ed over it
         INSIDE shard_map — N query instances of the same prepared shape run
         as one SPMD dispatch (collectives batch along the lane axis), and
-        every output gains a leading lane axis."""
+        every output gains a leading lane axis.  Partitions larger than
+        ``BATCH_VMAP_MAX_ROWS`` rows per node loop over the lanes instead
+        (``lax.map``): still one dispatch, one lane's working set."""
 
         in_specs = {
             name: {col: (P() if t.replicated else P(self.axis)) for col in t.columns}
@@ -132,12 +144,17 @@ class Cluster:
 
         if params:
             param_specs = {p.name: P() for p in params}
+            rows = max((t.num_rows // self.num_nodes for t in tables.values()
+                        if not t.replicated), default=0)
+            vmapped = vmaps_lanes(rows)
 
             def run(columns, pvals):
                 columns = entry(columns)
-                if batch:
-                    return jax.vmap(lambda pv: plan(ctx, columns, pv))(pvals)
-                return plan(ctx, columns, pvals)
+                if not batch:
+                    return plan(ctx, columns, pvals)
+                lane = lambda pv: plan(ctx, columns, pv)  # noqa: E731
+                return (jax.vmap(lane)(pvals) if vmapped
+                        else lax.map(lane, pvals))
 
             sharded = jax.shard_map(
                 run,

@@ -15,21 +15,16 @@ decode the column and filter raw:
 Packed wins when the saved bandwidth (raw bytes never streamed) exceeds
 the extra ALU cost of the in-place code test — the same
 codec-must-outrun-the-medium discipline the wire chooser applies to the
-network.  The crossover is a property of the MACHINE, so the rates are
-calibrated once (``python -m repro.core.scancal``), persisted under
-``experiments/bench/`` and loaded by the lowering; builtin defaults model
-the paper's bandwidth-bound nodes (memory far slower than the VPU →
-packed wins at every realistic width).
+network.  The crossover is a property of the MACHINE, so the rates live
+in one table keyed by ``device_kind`` (``jax.Device.device_kind``), each
+entry recording where its numbers came from.  ``python -m
+repro.core.scancal`` measures the rates of the device it runs on and
+prints the entry to add; a device kind missing from the table is an
+error, not a default.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
-from typing import Optional
-
-ENV_VAR = "REPRO_SCAN_CAL"
-DEFAULT_PATH = os.path.join("experiments", "bench", "scan_calibration.json")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,59 +35,41 @@ class ScanCalibration:
     predicate-on-packed throughput (values tested per second, SWAR
     kernel).  ``unpack_gvps``: full-column unpack throughput."""
 
-    mem_gbps: float = 6.0
-    scan_gvps: float = 4.0
-    unpack_gvps: float = 4.0
-    source: str = "builtin"
-
-    def to_json(self) -> dict:
-        return dataclasses.asdict(self)
-
-    @classmethod
-    def from_json(cls, d: dict) -> "ScanCalibration":
-        fields = {f.name for f in dataclasses.fields(cls)}
-        return cls(**{k: v for k, v in d.items() if k in fields})
+    mem_gbps: float
+    scan_gvps: float
+    unpack_gvps: float
+    source: str
 
 
-BUILTIN = ScanCalibration()
+TABLE = {
+    # CPU rehearsals (XLA's host backend): assumed rates of the paper's
+    # bandwidth-bound nodes, memory far slower than the vector units
+    "cpu": ScanCalibration(
+        mem_gbps=6.0, scan_gvps=4.0, unpack_gvps=4.0,
+        source="assumed: paper-era bandwidth-bound node, CPU rehearsals"),
+    "TPU v5 lite": ScanCalibration(
+        mem_gbps=283.5246371914444, scan_gvps=7.3719894591148,
+        unpack_gvps=5.3590444049601285,
+        source=("measured: python -m repro.core.scancal on one TPU v5 lite "
+                "(rows=67108864, width=12, best of 20), 2026-10-16")),
+}
 
 
 class ScanCalError(RuntimeError):
-    """An explicitly requested calibration file is missing or unusable
-    (same contract as :class:`repro.core.wirecal.WireCalError`)."""
+    """No calibration entry exists for the device kind in use."""
 
 
-def load(path: Optional[str] = None, *,
-         strict: Optional[bool] = None) -> ScanCalibration:
-    """Calibration from ``path`` / $REPRO_SCAN_CAL / the default location;
-    explicit sources raise on failure, the implicit default falls back to
-    :data:`BUILTIN`."""
-    explicit = path or os.environ.get(ENV_VAR)
-    if strict is None:
-        strict = explicit is not None
-    target = explicit or DEFAULT_PATH
+def for_device(device_kind: str) -> ScanCalibration:
+    """The table entry for ``device_kind``; raises :class:`ScanCalError`
+    for a device that was never calibrated."""
     try:
-        with open(target) as f:
-            return ScanCalibration.from_json(json.load(f))
-    except (OSError, ValueError, TypeError, AttributeError) as e:
-        if strict:
-            origin = "argument" if path else f"${ENV_VAR}"
-            kind = ("unreadable" if isinstance(e, OSError)
-                    else "not a calibration JSON object")
-            raise ScanCalError(
-                f"scan calibration file {target!r} (from {origin}) is "
-                f"{kind}: {e}") from e
-        return BUILTIN
-
-
-def save(cal: ScanCalibration, path: Optional[str] = None) -> str:
-    path = path or os.environ.get(ENV_VAR) or DEFAULT_PATH
-    d = os.path.dirname(path)
-    if d:
-        os.makedirs(d, exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(cal.to_json(), f, indent=1)
-    return path
+        return TABLE[device_kind]
+    except KeyError:
+        raise ScanCalError(
+            f"no scan calibration for device kind {device_kind!r} "
+            f"(known: {sorted(TABLE)}); measure it with `python -m "
+            f"repro.core.scancal` on that device and add the printed entry "
+            f"to repro.core.scancal.TABLE") from None
 
 
 # ---------------------------------------------------------------------------
@@ -119,21 +96,19 @@ def decode_scan_bytes(rows: int, width: int, itemsize: int = 4) -> int:
 
 
 def predict_packed_ms(rows: int, width: int, *,
-                      cal: Optional[ScanCalibration] = None) -> float:
-    cal = cal or BUILTIN
+                      cal: ScanCalibration) -> float:
     return (packed_scan_bytes(rows, width) / (cal.mem_gbps * 1e6)
             + rows / (cal.scan_gvps * 1e6))
 
 
 def predict_decode_ms(rows: int, width: int, itemsize: int = 4, *,
-                      cal: Optional[ScanCalibration] = None) -> float:
-    cal = cal or BUILTIN
+                      cal: ScanCalibration) -> float:
     return (decode_scan_bytes(rows, width, itemsize) / (cal.mem_gbps * 1e6)
             + rows / (cal.unpack_gvps * 1e6))
 
 
 def choose_scan_mode(rows: int, width: int, itemsize: int = 4, *,
-                     cal: Optional[ScanCalibration] = None) -> str:
+                     cal: ScanCalibration) -> str:
     """'packed' iff the roofline predicts the in-place code-space test is
     at least as fast as decoding the column and filtering raw."""
     packed = predict_packed_ms(rows, width, cal=cal)
@@ -142,14 +117,15 @@ def choose_scan_mode(rows: int, width: int, itemsize: int = 4, *,
 
 
 # ---------------------------------------------------------------------------
-# calibration (run once per machine)
+# calibration (run once per device kind)
 # ---------------------------------------------------------------------------
 
 
-def calibrate(*, rows: int = 1 << 20, width: int = 12, repeat: int = 20,
-              cal: Optional[ScanCalibration] = None) -> ScanCalibration:
+def calibrate(*, rows: int = 1 << 20, width: int = 12,
+              repeat: int = 20) -> ScanCalibration:
     """Measure streaming bandwidth, the jit'd predicate-on-packed kernel,
-    and the full unpack on a representative shape."""
+    and the full unpack on a representative shape, on the default
+    device."""
     import time
 
     import jax
@@ -159,7 +135,6 @@ def calibrate(*, rows: int = 1 << 20, width: int = 12, repeat: int = 20,
     from repro.core import compression
     from repro.kernels import ops
 
-    base = cal or BUILTIN
     padded = -(-rows // 32) * 32
     rng = np.random.default_rng(0)
     codes = jnp.asarray(
@@ -186,29 +161,29 @@ def calibrate(*, rows: int = 1 << 20, width: int = 12, repeat: int = 20,
     t_scan = best(lambda: ops.scan_filter(
         words, 1, 100, rows=rows, padded_rows=padded, width=width))
     t_unpack = best(lambda: unpack(words))
-    return dataclasses.replace(
-        base,
+    dev = jax.devices()[0]
+    return ScanCalibration(
         mem_gbps=rows * 4 / t_mem / 1e9,
         scan_gvps=rows / t_scan / 1e9,
         unpack_gvps=rows / t_unpack / 1e9,
-        source=f"calibrated(rows={rows},width={width})",
+        source=(f"measured: python -m repro.core.scancal on one "
+                f"{dev.device_kind} (rows={rows}, width={width}, "
+                f"best of {repeat})"),
     )
 
 
 def main(argv=None) -> int:
     import argparse
 
+    import jax
+
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--rows", type=int, default=1 << 20)
     ap.add_argument("--width", type=int, default=12)
     ap.add_argument("--repeat", type=int, default=20)
-    ap.add_argument("--out", type=str, default=None)
     args = ap.parse_args(argv)
-    cal = calibrate(rows=args.rows, width=args.width, repeat=args.repeat,
-                    cal=load(args.out, strict=False))
-    path = save(cal, args.out)
-    print(f"wrote {path}: mem {cal.mem_gbps:.2f} GB/s, "
-          f"scan {cal.scan_gvps:.2f} Gv/s, unpack {cal.unpack_gvps:.2f} Gv/s")
+    cal = calibrate(rows=args.rows, width=args.width, repeat=args.repeat)
+    print(f"{jax.devices()[0].device_kind!r}: {cal!r},")
     return 0
 
 

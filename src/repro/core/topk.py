@@ -39,10 +39,60 @@ def _rank_order(values, tiebreak, valid):
     return order
 
 
+# Partitions of at least this many rows select their top-k candidates
+# without sorting the partition: a TPU sort of millions of rows takes
+# minutes to compile, a select costs 64 counting passes.
+SELECT_MIN_ROWS = 1 << 16
+
+
+def _select(values, tiebreak, valid, k: int):
+    """Row indices (k,) of the top-k rows in rank order's SET (valid
+    first, value desc, tiebreak asc), unordered: the k-th largest order
+    key is found by a 32-step bitwise search of counts, then ties at it
+    by a second search over the tiebreak; a prefix count places the
+    selected rows."""
+    v = values.astype(jnp.float32) + jnp.float32(0.0)  # -0.0 ranks as 0.0
+    b = lax.bitcast_convert_type(v, jnp.uint32)
+    u = jnp.where((b >> 31) == 1, ~b, b | jnp.uint32(1 << 31))
+    u = jnp.where(valid, jnp.maximum(u, 1), jnp.uint32(0))
+    kk = lax.bitcast_convert_type(tiebreak.astype(jnp.int32),
+                                  jnp.uint32) ^ jnp.uint32(1 << 31)
+
+    def search(holds):
+        # largest uint32 t with holds(t), holds monotone and true at 0:
+        # one bit per step from the top
+        def step(i, t):
+            cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+            return jnp.where(holds(cand), cand, t)
+        return lax.fori_loop(0, 32, step, jnp.uint32(0))
+
+    def count(m):
+        return jnp.sum(m, dtype=jnp.int32)
+
+    t_k = search(lambda t: count(u >= t) >= k)    # the k-th largest key
+    need = k - count(u > t_k)                     # >= 1 rows tied at t_k
+    tied = u == t_k
+    k_tie = search(lambda t: count(tied & (kk < t)) < need)
+    sel = (u > t_k) | (tied & (kk <= k_tie))
+    return first_true(sel, k)[0]
+
+
+def first_true(mask, size: int):
+    """Indices of the first ``size`` true entries of ``mask``, in order,
+    and which of those slots exist — a left-pack by prefix count and
+    binary search, not a sort."""
+    pos = jnp.cumsum(mask, dtype=jnp.int32)
+    idx = jnp.searchsorted(pos, jnp.arange(1, size + 1, dtype=jnp.int32))
+    return jnp.minimum(idx, mask.shape[0] - 1), idx < mask.shape[0]
+
+
 def local_topk(values, keys, k: int, mask=None) -> TopK:
     """Top-k rows of the local partition by value (desc), key asc tiebreak."""
     n = values.shape[0]
     valid = jnp.ones(n, bool) if mask is None else mask
+    if n >= SELECT_MIN_ROWS and k < n:
+        rows = _select(values, keys, valid, k)
+        values, keys, valid = values[rows], keys[rows], valid[rows]
     order = _rank_order(values, keys, valid)[:k]
     return TopK(
         values=jnp.where(valid[order], values[order].astype(jnp.float32), NEG_INF),
@@ -97,9 +147,15 @@ def lazy_filtered_topk(
     local survivors are found (or the candidate pool is exhausted), then one
     merging reduction finds the global winners.
 
-    Static shapes: the candidate pool is fully sorted once; round i examines
-    slots [i*chunk, (i+1)*chunk).  max_rounds bounds the lax.while_loop.
+    Static shapes: the candidate pool is sorted once; round i examines
+    slots [i*chunk, (i+1)*chunk).  max_rounds bounds the lax.while_loop,
+    so only the best ``max_rounds * chunk`` candidates can ever be
+    examined: a large partition selects those first and sorts only them.
     """
+    pool = max_rounds * chunk
+    if values.shape[0] >= SELECT_MIN_ROWS and pool < values.shape[0]:
+        rows = _select(values, keys, mask, pool)
+        values, keys, mask = values[rows], keys[rows], mask[rows]
     n = values.shape[0]
     order = _rank_order(values, keys, mask)
     sv = jnp.where(mask[order], values[order].astype(jnp.float32), NEG_INF)

@@ -149,10 +149,9 @@ def approx_topk_distributed(
     cand_mask = hi >= threshold
     num_candidates = lax.psum(jnp.sum(cand_mask.astype(jnp.int32)), axis)
     C = min(candidate_capacity, Kp)
-    # stable left-pack candidate keys into a fixed buffer
-    order = jnp.argsort(~cand_mask, stable=True)
-    cand_keys = jnp.where(cand_mask[order], my_keys[order], 0)[:C]
-    cand_valid = cand_mask[order][:C]
+    # left-pack candidate keys into a fixed buffer
+    slots, cand_valid = topk_mod.first_true(cand_mask, C)
+    cand_keys = jnp.where(cand_valid, my_keys[slots], 0)
     overflow = jnp.sum(cand_mask.astype(jnp.int32)) > C
     # everyone learns everyone's candidates, answers with its exact partials
     all_cand = lax.all_gather(cand_keys, axis)          # (P, C) key ids

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK = 256  # bitset words (row groups of 32) per Pallas grid step
+DEFAULT_BLOCK = 2048  # bitset words (row groups of 32) per Pallas grid step
 
 
 def _check(padded_rows: int, width: int) -> int:
@@ -33,17 +33,18 @@ def _check(padded_rows: int, width: int) -> int:
     return padded_rows // 32
 
 
-def _group_scan(W, lo, hi, base, *, rows: int, width: int, negate: bool):
-    """Shared SWAR body: W (R, width) uint32 word groups, base (R, 1) int32
-    first-row index of each group -> (R, 1) uint32 bitset words."""
+def _group_scan(word, lo, hi, base, *, rows: int, width: int, negate: bool):
+    """Shared SWAR body.  ``word(wi)`` returns word ``wi`` of every 32-row
+    group, ``base`` (same shape) the first row index of each group; the
+    result holds one uint32 bitset word per group."""
     mask = jnp.uint32((1 << width) - 1)
     out = jnp.zeros(base.shape, jnp.uint32)
     for j in range(32):
         bit = j * width
         wi, off = bit >> 5, bit & 31
-        va = W[:, wi:wi + 1] >> jnp.uint32(off)
+        va = word(wi) >> jnp.uint32(off)
         if off + width > 32:  # static straddle test
-            va = va | (W[:, wi + 1:wi + 2] << jnp.uint32(32 - off))
+            va = va | (word(wi + 1) << jnp.uint32(32 - off))
         code = (va & mask).astype(jnp.int32)
         ok = (code >= lo) & (code <= hi)
         if negate:
@@ -58,43 +59,51 @@ def scan_filter_xla(words, lo, hi, *, rows: int, padded_rows: int,
     """Pure-XLA formulation; returns (padded_rows/32,) uint32 bitset."""
     R = _check(padded_rows, width)
     W = words.reshape(R, width)
-    base = (jnp.arange(R, dtype=jnp.int32) * 32)[:, None]
-    return _group_scan(W, jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-                       base, rows=rows, width=width, negate=negate)[:, 0]
+    base = jnp.arange(R, dtype=jnp.int32) * 32
+    return _group_scan(lambda wi: W[:, wi], jnp.asarray(lo, jnp.int32),
+                       jnp.asarray(hi, jnp.int32), base, rows=rows,
+                       width=width, negate=negate)
 
 
-def _kernel(bounds_ref, w_ref, out_ref, *, rows, width, negate, br):
+def _kernel(bounds_ref, w_ref, out_ref, *, rows, width, negate, bc):
     b = bounds_ref[...]                           # (1, 2) int32
     lo, hi = b[0, 0], b[0, 1]
-    W = w_ref[...]                                # (br, width) uint32
-    r0 = pl.program_id(0) * br
-    base = (jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0) + r0) * 32
-    out_ref[...] = _group_scan(W, lo, hi, base, rows=rows, width=width,
-                               negate=negate)
+    W = w_ref[...]                                # (width, bc) uint32
+    r0 = pl.program_id(0) * bc
+    base = (jax.lax.broadcasted_iota(jnp.int32, (1, bc), 1) + r0) * 32
+    out_ref[...] = _group_scan(lambda wi: W[wi:wi + 1, :], lo, hi, base,
+                               rows=rows, width=width, negate=negate)
 
 
 def scan_filter_pallas(words, lo, hi, *, rows: int, padded_rows: int,
                        width: int, negate: bool = False,
                        block: int = DEFAULT_BLOCK, interpret: bool = False):
-    """Pallas lane-kernel formulation (grid over row groups)."""
+    """Pallas lane-kernel formulation.  The word stream is viewed
+    group-transposed, ``(width, groups)``: word ``wi`` of every group is
+    one lane-dense row, so the 32 extractions are static row slices and
+    the output is one lane-dense row of bitset words.  (A ``(groups,
+    width)`` block would pad ``width`` to the 128 lanes in HBM.)"""
     R = _check(padded_rows, width)
-    W = words.reshape(R, width)
-    br = min(block, R)
-    pad = (-R) % br
+    # row wi = word wi of every group: strided slices, not a 2-D reshape
+    # (whose (groups, width) layout would be padded to 128 lanes)
+    Wt = jnp.stack([jax.lax.slice(words, (wi,), (wi + (R - 1) * width + 1,),
+                                  (width,)) for wi in range(width)])
+    bc = min(block, R)
+    pad = (-R) % bc
     if pad:  # zero groups decode to code 0 but base >= rows masks them off
-        W = jnp.pad(W, ((0, pad), (0, 0)))
+        Wt = jnp.pad(Wt, ((0, 0), (0, pad)))
     Rp = R + pad
     bounds = jnp.stack([jnp.asarray(lo, jnp.int32),
                         jnp.asarray(hi, jnp.int32)]).reshape(1, 2)
     kernel = functools.partial(_kernel, rows=rows, width=width,
-                               negate=negate, br=br)
+                               negate=negate, bc=bc)
     out = pl.pallas_call(
         kernel,
-        grid=(Rp // br,),
+        grid=(Rp // bc,),
         in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0)),
-                  pl.BlockSpec((br, W.shape[1]), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Rp, 1), jnp.uint32),
+                  pl.BlockSpec((width, bc), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, bc), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Rp), jnp.uint32),
         interpret=interpret,
-    )(bounds, W)
-    return out[:R, 0]
+    )(bounds, Wt)
+    return out[0, :R]

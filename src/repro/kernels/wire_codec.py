@@ -22,8 +22,8 @@ Two tiers, selected by ``use_pallas``:
   or scatters a ``capacity``-sized index set.
 
 - Pallas kernels for the bandwidth-bound lane stages (mask fold/unfold,
-  EF lower-bits pack/unpack when ``32 % l == 0``), one destination row
-  per grid step.  ``interpret=True`` runs them anywhere for parity
+  EF lower-bits pack/unpack when ``32 % l == 0``), tiled over blocks of
+  packed words.  ``interpret=True`` runs them anywhere for parity
   testing; the compiled path is for real accelerator backends —
   interpret mode executes Python per grid step and would lose the
   exchange latency gate, so CPU dispatch (``kernels.ops``) uses the XLA
@@ -53,45 +53,68 @@ def _popcount(x):
 
 
 # ---------------------------------------------------------------------------
-# Pallas lane kernels: one destination row per grid step
+# Pallas lane kernels: fixed-width fields <-> uint32 words
+#
+# Both wire stages that have a kernel are the same operation at two field
+# widths: the validity mask packs 32 one-bit fields per word, the EF lower
+# bits 32/l l-bit fields.  The kernels see the fields as an (N, k) array,
+# one word per row, and tile N in row blocks: a block is (br, k) with k the
+# full minor dim, so every block shape the TPU lowering accepts.  Packing is
+# a static unrolled OR of shifted one-lane slices (disjoint bits, so OR ==
+# the reference's sum) — no reduction over unsigned integers, which Mosaic
+# does not lower.
 # ---------------------------------------------------------------------------
 
-
-def _mask_fold_kernel(mask_ref, out_ref):
-    bits = mask_ref[...].astype(jnp.uint32).reshape(-1, 32)
-    w = jnp.uint32(1) << jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
-    out_ref[...] = jnp.sum(bits * w, axis=1, dtype=jnp.uint32).reshape(1, -1)
+_BLOCK_WORDS = 1024  # word rows per grid step (multiple of 8)
 
 
-def _mask_unfold_kernel(words_ref, out_ref):
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
-    bits = (words_ref[...][:, :, None] >> lane) & jnp.uint32(1)
-    out_ref[...] = bits.astype(jnp.bool_).reshape(1, -1)
+def _pack_kernel(x_ref, out_ref, *, shift):
+    x = x_ref[...]                                # (br, k) uint32 fields
+    out = x[:, 0:1]
+    for j in range(1, x.shape[1]):
+        out = out | (x[:, j:j + 1] << jnp.uint32(j * shift))
+    out_ref[...] = out
 
 
-def _lower_pack_kernel(vals_ref, out_ref, *, l):
-    k = 32 // l
-    x = vals_ref[...].reshape(-1, k)
-    sh = jax.lax.broadcasted_iota(jnp.uint32, (1, k), 1) * jnp.uint32(l)
-    out_ref[...] = jnp.sum(x << sh, axis=1, dtype=jnp.uint32).reshape(1, -1)
+def _unpack_kernel(w_ref, out_ref, *, shift):
+    w = w_ref[...]                                # (br, 1) uint32 words
+    sh = jax.lax.broadcasted_iota(jnp.uint32, out_ref.shape, 1) \
+        * jnp.uint32(shift)
+    out_ref[...] = (w >> sh) & jnp.uint32((1 << shift) - 1)
 
 
-def _lower_unpack_kernel(words_ref, out_ref, *, l):
-    k = 32 // l
-    sh = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, k), 2) * jnp.uint32(l)
-    x = (words_ref[...][:, :, None] >> sh) & jnp.uint32((1 << l) - 1)
-    out_ref[...] = x.reshape(1, -1)
-
-
-def _row_call(kernel, rows, in_cols, out_cols, out_dtype, interpret):
-    return pl.pallas_call(
+def _word_call(kernel, x, out_cols, interpret):
+    """Run ``kernel`` over the rows of ``x`` (N, in_cols) uint32 in blocks
+    of ``_BLOCK_WORDS`` rows (zero-padded); returns (N, out_cols) uint32."""
+    n, in_cols = x.shape
+    br = min(_BLOCK_WORDS, n)
+    pad = (-n) % br
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    out = pl.pallas_call(
         kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, in_cols), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, out_cols), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, out_cols), out_dtype),
+        grid=((n + pad) // br,),
+        in_specs=[pl.BlockSpec((br, in_cols), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((br, out_cols), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n + pad, out_cols), jnp.uint32),
         interpret=interpret,
-    )
+    )(x)
+    return out[:n]
+
+
+def _pack_fields(fields, shift: int, interpret: bool):
+    """(..., W, k) uint32 fields of ``shift`` bits -> (..., W) words."""
+    *lead, k = fields.shape
+    kernel = functools.partial(_pack_kernel, shift=shift)
+    out = _word_call(kernel, fields.reshape(-1, k), 1, interpret)
+    return out.reshape(lead)
+
+
+def _unpack_fields(words, k: int, shift: int, interpret: bool):
+    """Inverse of :func:`_pack_fields`: (..., W) words -> (..., W, k)."""
+    kernel = functools.partial(_unpack_kernel, shift=shift)
+    out = _word_call(kernel, words.reshape(-1, 1), k, interpret)
+    return out.reshape(*words.shape, k)
 
 
 # ---------------------------------------------------------------------------
@@ -106,10 +129,9 @@ def mask_fold(mask, *, use_pallas: bool = False, interpret: bool = False):
     if pad:
         mask = jnp.pad(mask, ((0, 0), (0, pad)))
     cw = mask.shape[1] // 32
-    if use_pallas:
-        return _row_call(_mask_fold_kernel, rows, cw * 32, cw,
-                         jnp.uint32, interpret)(mask)
     x = mask.reshape(rows, cw, 32).astype(jnp.uint32)
+    if use_pallas:
+        return _pack_fields(x, 1, interpret)
     w = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)[None, None, :]
     return jnp.sum(x * w, axis=2, dtype=jnp.uint32)
 
@@ -119,11 +141,10 @@ def mask_unfold(words, n: int, *, use_pallas: bool = False,
     """Inverse of :func:`mask_fold`: (P, w) uint32 -> (P, n) bool."""
     rows, cw = words.shape
     if use_pallas:
-        bits = _row_call(_mask_unfold_kernel, rows, cw, cw * 32,
-                         jnp.bool_, interpret)(words)
-        return bits[:, :n]
-    lane = jnp.arange(32, dtype=jnp.uint32)[None, None, :]
-    bits = ((words[:, :, None] >> lane) & jnp.uint32(1)).astype(bool)
+        bits = _unpack_fields(words, 32, 1, interpret).astype(bool)
+    else:
+        lane = jnp.arange(32, dtype=jnp.uint32)[None, None, :]
+        bits = ((words[:, :, None] >> lane) & jnp.uint32(1)).astype(bool)
     return bits.reshape(rows, cw * 32)[:, :n]
 
 
@@ -140,10 +161,9 @@ def _lower_pack(lov, l: int, lw: int, use_pallas, interpret):
         pad = lw * k - cap
         if pad:
             lov = jnp.pad(lov, ((0, 0), (0, pad)))
-        if use_pallas:
-            return _row_call(functools.partial(_lower_pack_kernel, l=l),
-                             rows, lw * k, lw, jnp.uint32, interpret)(lov)
         x = lov.reshape(rows, lw, k)
+        if use_pallas:
+            return _pack_fields(x, l, interpret)
         sh = (jnp.arange(k, dtype=jnp.uint32) * jnp.uint32(l))[None, None, :]
         return jnp.sum(x << sh, axis=2, dtype=jnp.uint32)
     # straddling width: each word collects the <= ceil(32/l)+1 values that
@@ -173,9 +193,8 @@ def _lower_unpack(lower, l: int, cap: int, use_pallas, interpret):
     if 32 % l == 0:
         k = 32 // l
         if use_pallas:
-            vals = _row_call(functools.partial(_lower_unpack_kernel, l=l),
-                             rows, lw, lw * k, jnp.uint32, interpret)(lower)
-            return vals[:, :cap]
+            vals = _unpack_fields(lower, k, l, interpret)
+            return vals.reshape(rows, lw * k)[:, :cap]
         sh = (jnp.arange(k, dtype=jnp.uint32) * jnp.uint32(l))[None, None, :]
         vals = (lower[:, :, None] >> sh) & jnp.uint32((1 << l) - 1)
         return vals.reshape(rows, lw * k)[:, :cap]

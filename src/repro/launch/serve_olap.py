@@ -28,7 +28,8 @@ import os
 import sys
 import time
 
-# serve on the standard 8-node host cluster unless the caller pinned a mesh
+# CPU rehearsals run on an 8-device host mesh unless the caller pinned one;
+# only XLA's CPU backend reads this flag, a TPU run ignores it
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 
@@ -108,7 +109,7 @@ def _serve_engine(d, args):
     import asyncio
 
     from repro.serve import workload as wl
-    from repro.serve.olap_engine import OLAPEngine
+    from repro.serve.olap_engine import AdmissionError, OLAPEngine
 
     t0 = time.monotonic()
     d.build_cubes()
@@ -144,6 +145,8 @@ def _serve_engine(d, args):
 
     res, wall, stats = asyncio.run(go())
     rep = wl.summarize(res, wall)
+    errors = [c for c in res
+              if not c.ok and not isinstance(c.answer, AdmissionError)]
     print(f"\n{'class':>8s} {'n':>6s} {'p50[ms]':>9s} {'p95[ms]':>9s} "
           f"{'p99[ms]':>9s} {'mean[ms]':>9s}")
     for kind, s in rep["kinds"].items():
@@ -157,7 +160,9 @@ def _serve_engine(d, args):
           f"mean size {bs.get('mean', 0):.1f}, p95 {bs.get('p95', 0):.0f}); "
           f"tier1 inline {stats['tier1']}, solo {stats['solo']}, "
           f"rejected {stats['rejected']}")
-    return 0
+    for c in errors[:5]:
+        print(f"request {c.item.name} failed: {c.answer!r}", file=sys.stderr)
+    return 1 if errors else 0
 
 
 def main(argv=None):
@@ -201,6 +206,7 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from repro import compile_cache
     from repro.core.plans import PLANS
     from repro.tpch.driver import TPCHDriver
 
@@ -216,6 +222,7 @@ def main(argv=None):
                   file=sys.stderr)
             return 2
 
+    compile_cache.enable()
     d = TPCHDriver(sf=args.sf, seed=args.seed, backend=args.backend)
     try:
         if args.lint:

@@ -623,6 +623,7 @@ class Catalog:
     tables: Mapping[str, TableInfo]
     copartitioned: Mapping[str, tuple]
     num_nodes: int = 1
+    device_kind: str = "cpu"  # jax device_kind the plans run on
 
     def table(self, name: str) -> TableInfo:
         try:
@@ -636,13 +637,14 @@ class Catalog:
 def build_catalog(tables: Mapping[str, object], *, num_nodes: int = 1,
                   copartitioned: Optional[Mapping[str, tuple]] = None,
                   packed: Optional[Mapping[str, Mapping[str, PackedInfo]]] = None,
-                  ) -> Catalog:
+                  device_kind: str = "cpu") -> Catalog:
     """Catalog from host-side ``Table`` objects (the driver's
     ``self.tables``): column names, replication, and min/max/distinct
     stats feeding the selectivity model.  ``packed`` optionally declares
     the resident encoding per table/column (the driver derives it from
     the packed resident tables) — the lowering and the SCAN001 verifier
-    rule key off it."""
+    rule key off it.  ``device_kind`` names the device the plans will run
+    on; the lowering takes its scan-roofline rates from it."""
     infos = {}
     for name, t in tables.items():
         stats = {}
@@ -671,6 +673,7 @@ def build_catalog(tables: Mapping[str, object], *, num_nodes: int = 1,
         copartitioned=dict(TPCH_COPARTITIONED if copartitioned is None
                            else copartitioned),
         num_nodes=num_nodes,
+        device_kind=device_kind,
     )
 
 

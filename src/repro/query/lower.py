@@ -45,6 +45,7 @@ from repro.core import aggregation, late_materialization, semijoin, topk
 from repro.core import compression, scancal, wirecal
 from repro.core.columnar import PackedColumn
 from repro.core.compression import choose_semijoin_wire
+from repro.core.engine import vmaps_lanes
 from repro.core.exchange import WireFormat
 from repro.query import stats as qstats
 from repro.query.ir import (
@@ -205,18 +206,18 @@ def _decide_semijoins(root, catalog: Catalog, query_name=None,
     return decisions
 
 
-def _decide_scans(root, catalog: Catalog, cal=None) -> dict:
+def _decide_scans(root, catalog: Catalog) -> dict:
     """Per-Filter predicate-on-packed decisions over compressed-resident
     base tables: each filter conjunct that is a ``col op scalar``
     comparison against a packed column rewrites into a code-space range
     test the scan kernel evaluates on the packed words directly
     (``repro.query.stats.scan_rewrite``); the :mod:`repro.core.scancal`
-    roofline arbitrates packed vs decode per column.  Same-column range
+    roofline, at the rates of the catalog's device kind, arbitrates packed
+    vs decode per column.  Same-column range
     tests fuse into one scan (``qstats.merge_scan_conjuncts``).  Returns
     ``{id(filter): [(conjuncts_tuple, [ScanDecision, ...]), ...]}`` for
     filters touching at least one packed column."""
-    if cal is None:
-        cal = scancal.load(strict=False)
+    cal = scancal.for_device(catalog.device_kind)
     decisions = {}
     base = None
     for node in _chain(root):
@@ -487,6 +488,12 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
     sj_plans = _decide_semijoins(root, catalog, query_name=query.name,
                                  wire=wire, binding=binding)
     scan_plans = _decide_scans(root, catalog)
+    # the mask-GEMM only pays where the cluster vmaps batch lanes; larger
+    # partitions run them one by one (Cluster.compile), where the plain
+    # lowering is the same work
+    lanes_vmapped = vmaps_lanes(
+        max((t.num_rows for t in catalog.tables.values()
+             if not t.replicated), default=0) // max(catalog.num_nodes, 1))
     if obs is not None:
         obs.event(
             "lower", cat="plan",
@@ -628,7 +635,8 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                 method = root.method
                 if method == "auto":
                     method = "onehot" if num_groups <= ONEHOT_MAX_GROUPS else "dense"
-                    if batched and _maskgemm_eligible(root, num_groups):
+                    if (batched and lanes_vmapped
+                            and _maskgemm_eligible(root, num_groups)):
                         method = "maskgemm"
                 if num_groups == 1:
                     # global aggregate: per-measure masked tree-sums (the
@@ -645,22 +653,12 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                 elif method == "maskgemm":
                     # batched-lowering form: group codes and measures are
                     # parameter-independent, only the filter mask varies
-                    # per lane — contract the lane mask against the
-                    # pre-expanded (n, G*M) one-hot (x) measure tensor so
-                    # vmap batches a single GEMM, not the whole pipeline.
-                    # Out-of-range codes match no one-hot column and drop
-                    # out, like the onehot path.
+                    # per lane, so vmap batches one GEMM per row block,
+                    # not the whole pipeline
                     gid = _group_ids(root, s, pv, clip=False)
                     stacked = _measure_stack(root.aggs, s.cols, None, pv)
-                    n, m = stacked.shape
-                    onehot = (gid[:, None]
-                              == jnp.arange(num_groups, dtype=jnp.int32)
-                              ).astype(jnp.float32)
-                    expanded = (onehot[:, :, None] * stacked[:, None, :]
-                                ).reshape(n, num_groups * m)
-                    maskf = (jnp.ones(n, jnp.float32) if s.mask is None
-                             else s.mask.astype(jnp.float32))
-                    local = (maskf @ expanded).reshape(num_groups, m)
+                    local = aggregation.group_sum_maskgemm(
+                        stacked, gid, num_groups, s.mask)
                 elif method == "onehot":
                     # out-of-range codes match no one-hot row and drop out,
                     # so no clamp pass is needed (keeps the HLO identical

@@ -319,7 +319,7 @@ class ScanDecision:
 
 def decide_scan_conjunct(conjunct: Expr, table_name: str,
                          packed: Mapping[str, PackedInfo],
-                         rows_per_node: int, *, cal=None) -> list:
+                         rows_per_node: int, *, cal) -> list:
     """Scan strategy for one filter conjunct over a packed-resident base
     table: one :class:`ScanDecision` per packed column the conjunct
     touches.  Rewritable predicates go packed iff the roofline says the

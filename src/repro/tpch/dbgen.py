@@ -16,6 +16,7 @@ Only nation/region (25/5 rows) are replicated (paper: tables <= ~50 rows).
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from repro.core.columnar import Table, concat_tables, pack_column, plan_packing
@@ -35,8 +36,15 @@ def table_sizes(sf: float, num_nodes: int) -> dict:
     return sizes
 
 
+# Each table's random stream is keyed by its index here: a fixed id, so a
+# seed gives the same data in every process (``hash(str)`` is salted per
+# process and cannot key a reproducible stream).
+PARTITIONED_TABLES = ("supplier", "customer", "part", "partsupp", "orders",
+                      "lineitem")
+
+
 def _rng(seed: int, table: str, node: int) -> np.random.Generator:
-    ss = np.random.SeedSequence([seed, hash(table) & 0x7FFFFFFF, node])
+    ss = np.random.SeedSequence([seed, PARTITIONED_TABLES.index(table), node])
     return np.random.default_rng(ss)
 
 
@@ -214,31 +222,42 @@ def generate(sf: float, num_nodes: int, seed: int = 0,
     """Global tables assembled from per-node chunks (host-side; used by the
     driver to place data and by the oracle for correctness checks).
 
-    ``storage="packed"`` generates eligible columns straight into the
-    compressed-resident :class:`~repro.core.columnar.PackedColumn` format
-    (dictionary / frame-of-reference bit-packing, globally consistent
-    width/offset/dictionary across node chunks) — the raw global column is
-    never materialized.  Ineligible columns (wide key spans, high-entropy
-    floats) stay raw; replicated tables always stay raw."""
+    ``storage="packed"`` returns the compressed-resident form
+    (:func:`pack_tables`)."""
     if storage not in ("raw", "packed"):
         raise ValueError(f"storage must be 'raw' or 'packed', got {storage!r}")
     chunks = [generate_node(sf, node, num_nodes, seed) for node in range(num_nodes)]
-    tables = {}
-    for name in ("supplier", "customer", "part", "partsupp", "orders", "lineitem"):
-        if storage == "packed":
+    tables = {
+        name: concat_tables([Table(name, chunks[n][name],
+                                   DICTIONARIES.get(name, {}))
+                             for n in range(num_nodes)])
+        for name in PARTITIONED_TABLES
+    }
+    tables.update(_replicated_tables())
+    return pack_tables(tables, num_nodes) if storage == "packed" else tables
+
+
+def pack_tables(tables: dict, num_nodes: int) -> dict:
+    """The compressed-resident form of raw generated tables: eligible
+    columns of the partitioned tables become
+    :class:`~repro.core.columnar.PackedColumn`s (dictionary /
+    frame-of-reference bit-packing, one width/offset/dictionary across the
+    node chunks); ineligible columns (wide key spans, high-entropy floats)
+    and replicated tables stay raw.  Packing is lossless, so the raw
+    tables remain a bit-identical host view.  It runs on the host's CPU
+    backend: this is set-up work on host data, which eager ops on an
+    accelerator would only ship back and forth."""
+    with jax.default_device(jax.local_devices(backend="cpu")[0]):
+        out = {}
+        for name, t in tables.items():
+            if t.replicated:
+                out[name] = t
+                continue
             cols = {}
-            for cname in chunks[0][name]:
-                cchunks = [chunks[n][name][cname] for n in range(num_nodes)]
+            for cname, col in t.columns.items():
+                cchunks = np.split(np.asarray(col), num_nodes)
                 spec = plan_packing(cchunks)
                 cols[cname] = (pack_column(cchunks, spec)
-                               if spec is not None
-                               else np.concatenate(cchunks))
-            tables[name] = Table(name, cols, DICTIONARIES.get(name, {}))
-        else:
-            parts = [
-                Table(name, chunks[n][name], DICTIONARIES.get(name, {}))
-                for n in range(num_nodes)
-            ]
-            tables[name] = concat_tables(parts)
-    tables.update(_replicated_tables())
-    return tables
+                               if spec is not None else col)
+            out[name] = Table(name, cols, t.dictionaries)
+    return out

@@ -392,23 +392,20 @@ class TPCHDriver:
         # §3.2.2-derived capacities for the hand plans; explicit overrides win
         self.capacities = tpch_capacities.derive(sf, self.cluster.num_nodes)
         self.capacities.update(capacities or {})
-        # resident storage format: "packed" generates eligible columns
-        # straight into the compressed PackedColumn form; self.tables stays
-        # a DECODED host-side view (bit-identical to the packed codes) for
-        # the oracle and catalog stats, while self.resident is what the
-        # cluster actually holds and places
-        self.resident = dbgen.generate(sf, self.cluster.num_nodes, seed,
-                                       storage=storage)
-        if storage == "packed":
-            self.tables = {
-                n: Table(n, {c: (np.asarray(col.decode())
-                                 if isinstance(col, PackedColumn) else col)
-                             for c, col in t.columns.items()},
-                         t.dictionaries, t.replicated)
-                for n, t in self.resident.items()
-            }
-        else:
-            self.tables = self.resident
+        # self.tables is the raw host-side view for the oracle and catalog
+        # stats; self.resident is what the cluster holds and places — with
+        # storage="packed", eligible columns in the compressed PackedColumn
+        # form (lossless, so the host view is bit-identical to the codes).
+        # load_seconds: host seconds of each load step (set-up, not query)
+        self.load_seconds = {}
+        t0 = time.perf_counter()
+        self.tables = dbgen.generate(sf, self.cluster.num_nodes, seed)
+        self.load_seconds["generate"] = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        self.resident = (dbgen.pack_tables(self.tables,
+                                           self.cluster.num_nodes)
+                         if storage == "packed" else dict(self.tables))
+        self.load_seconds["pack"] = time.perf_counter() - t0
         # pad the supplier key space so §3.2.5 groups divide evenly
         self._extend_derived_tables()
         for extra in set(self.tables) - set(self.resident):
@@ -422,7 +419,8 @@ class TPCHDriver:
         }
         self.catalog = build_catalog(self.tables,
                                      num_nodes=self.cluster.num_nodes,
-                                     packed=packed_meta)
+                                     packed=packed_meta,
+                                     device_kind=self.cluster.device_kind)
         # resident-footprint accounting + node memory budget: the budget
         # models per-node main memory; exceeding it is the OOM the packed
         # format exists to push out by ~the compression ratio
@@ -444,8 +442,11 @@ class TPCHDriver:
                 f"{storage!r} storage but the node budget is "
                 f"{resident_budget} bytes (fully decoded it would be "
                 f"{raw}); use storage='packed' or a smaller scale factor")
+        t0 = time.perf_counter()
         self.placed = {n: self.cluster.load(t)
                        for n, t in self.resident.items()}
+        jax.block_until_ready([t.columns for t in self.placed.values()])
+        self.load_seconds["place"] = time.perf_counter() - t0
         self.ctx = self.cluster.context(
             self.placed, self.capacities, backend=backend, scale_factor=sf,
             wire=wire,
@@ -546,6 +547,16 @@ class TPCHDriver:
     def run(self, name: str):
         return self.compile(name)(self._columns())
 
+    def use_backend(self, backend: str) -> None:
+        """Compile every later plan with the ``backend`` all-to-all
+        ("xla" | "one_factor") over the same placed data; compiled plans
+        are dropped, cubes and data stay."""
+        with self._lock:
+            self.backend = backend
+            self.ctx = dataclasses.replace(self.ctx, backend=backend)
+            self._compiled.clear()
+            self._prepared.clear()
+
     def compile_ir(self, name: str):
         """Compiled LOWERED plan for a registered query's IR (even when a
         hand plan exists — used to compare the two)."""
@@ -638,6 +649,9 @@ class TPCHDriver:
                 on_trace()
                 return plan(ctx, t)
         wrapped.params = plan.params
+        # the lowering scans packed columns itself; without this flag
+        # Cluster.compile would decode every column at plan entry
+        wrapped.handles_packed = plan.handles_packed
         return wrapped
 
     def _ensure_compiled(self, entry: _PlanEntry):
@@ -884,6 +898,26 @@ class TPCHDriver:
         report.observed = observed
         return report
 
+    def lowered_text(self, q) -> str:
+        """StableHLO text of the scalar Tier-2 plan of ``q`` (an IR query
+        or a registered name) as lowered for the cluster's devices; on a
+        TPU each natively compiled Pallas kernel is a ``tpu_custom_call``.
+        Nothing is compiled."""
+        return self._lowered(self.prepare(q).entry).as_text()
+
+    def _lowered(self, entry: _PlanEntry):
+        fn = self._ensure_compiled(entry)
+        cols = self._columns()
+        self._profiling = True
+        try:
+            if entry.params:
+                pvals = {p.name: jax.ShapeDtypeStruct(
+                    (), np.dtype(p.dtype)) for p in entry.params}
+                return fn.lower(cols, pvals)
+            return fn.lower(cols)
+        finally:
+            self._profiling = False
+
     def _collective_profile(self, entry: _PlanEntry):
         """HLO collective stats of the compiled scalar plan, cached per
         entry.  Lazy on purpose: ``jit(...).lower().compile()`` is a second
@@ -892,20 +926,8 @@ class TPCHDriver:
         if entry.profile is None:
             from repro.launch.roofline import parse_collective_bytes
 
-            fn = self._ensure_compiled(entry)
-            cols = self._columns()
-            self._profiling = True
-            try:
-                if entry.params:
-                    pvals = {p.name: jax.ShapeDtypeStruct(
-                        (), np.dtype(p.dtype)) for p in entry.params}
-                    lowered = fn.lower(cols, pvals)
-                else:
-                    lowered = fn.lower(cols)
-                entry.profile = parse_collective_bytes(
-                    lowered.compile().as_text())
-            finally:
-                self._profiling = False
+            entry.profile = parse_collective_bytes(
+                self._lowered(entry).compile().as_text())
         return entry.profile
 
     def oracle(self, name: str, **kw):

@@ -13,8 +13,9 @@ import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+from repro.tpch.reference import assert_topk_matches  # noqa: E402,F401
 
 
 @pytest.fixture(scope="session")
@@ -37,39 +38,3 @@ def tpch_driver_seed1(cluster):
     from repro.tpch.driver import TPCHDriver
 
     return TPCHDriver(sf=0.02, cluster=cluster, seed=1)
-
-
-def assert_topk_matches(values, keys, valid, oracle_values, oracle_keys,
-                        rtol=2e-3, atol=1e-2):
-    """Compare a plan TopK (values desc, key asc ties) against the float64
-    numpy oracle.  Positionwise value check + key-set check with tolerance
-    for rank flips between near-equal float32/float64 aggregates."""
-    values = np.asarray(values, np.float64)
-    keys = np.asarray(keys, np.int64)
-    valid = np.asarray(valid, bool)
-    n_valid = int(valid.sum())
-    ov = np.asarray(oracle_values, np.float64)
-    ok = np.asarray(oracle_keys, np.int64)
-    o_valid = np.isfinite(ov)
-    n_oracle = int(o_valid.sum())
-    # the plan may be capped below the oracle's k on tiny data; compare the
-    # overlapping prefix
-    n = min(n_valid, n_oracle) if len(values) != len(ov) else max(n_valid, n_oracle)
-    assert n_valid >= min(n, n_oracle), (
-        f"plan found {n_valid} rows, oracle {n_oracle}"
-    )
-    pv, pk = values[:n], keys[:n]
-    qv, qk = ov[:n], ok[:n]
-    np.testing.assert_allclose(pv, qv, rtol=rtol, atol=atol)
-    mismatched = pk != qk
-    if mismatched.any():
-        # allow key mismatches only where the values tie within tolerance
-        tied = np.isclose(pv, qv, rtol=rtol, atol=atol)
-        assert (mismatched <= tied).all(), (
-            f"key mismatch outside value ties:\nplan {list(zip(pk, pv))}\n"
-            f"oracle {list(zip(qk, qv))}"
-        )
-        # and the key multisets must still agree on the tied region
-        assert sorted(pk.tolist()) == sorted(qk.tolist()) or np.allclose(
-            np.sort(pv), np.sort(qv), rtol=rtol, atol=atol
-        )

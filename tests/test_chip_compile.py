@@ -1,0 +1,106 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real sizes.
+
+Interpret mode (every other kernel test) runs a kernel's Python body on
+the CPU and accepts block shapes, dtypes and reductions that the chip's
+compiler refuses.  Here each kernel is compiled, not run, for one chip of
+a described ``v5e:2x2`` topology with the TPU compiler that ships with
+jaxlib, at the shapes of TPC-H SF 10 on one chip or a four-chip cluster,
+and the compiled HLO must hold the kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the test workers each
+import every test file.  The fixture skips where no topology can be
+described.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core import compression
+from repro.kernels import grouped_agg, scan_filter, wire_codec
+
+pytestmark = pytest.mark.tier1
+
+LINEITEM_ROWS = 60_000_000    # SF 10 lineitem on one chip
+PART_ROWS = 2_000_000         # SF 10 part: the exchange key domain
+CAPACITY = 1 << 18            # keys per destination of a request exchange
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _compiled_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("width", [1, 12, 24])
+def test_scan_filter_compiles(one_chip, width):
+    words = compression.packed_words(LINEITEM_ROWS, width)
+
+    def scan(w, lo, hi):
+        return scan_filter.scan_filter_pallas(
+            w, lo, hi, rows=LINEITEM_ROWS, padded_rows=LINEITEM_ROWS,
+            width=width)
+
+    text = _compiled_text(scan, one_chip, ((words,), jnp.uint32),
+                          ((), jnp.int32), ((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_filtered_group_sum_compiles(one_chip):
+    n = 16_000_000
+
+    def agg(measures, groups, pred):
+        return grouped_agg.filtered_group_sum(measures, groups, pred,
+                                              cutoff=10_000, num_groups=6)
+
+    text = _compiled_text(agg, one_chip, ((n, 8), jnp.float32),
+                          ((n,), jnp.int32), ((n,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("nodes", [1, 4])
+@pytest.mark.parametrize("stage", ["mask_fold", "mask_unfold", "ef_encode",
+                                   "ef_decode"])
+def test_wire_codec_compiles(one_chip, nodes, stage):
+    domain = PART_ROWS // nodes
+    rows = (nodes, CAPACITY)
+    if stage == "mask_fold":
+        fn = lambda m: wire_codec.mask_fold(m, use_pallas=True)  # noqa: E731
+        shapes = ((rows, jnp.bool_),)
+    elif stage == "mask_unfold":
+        fn = lambda w: wire_codec.mask_unfold(  # noqa: E731
+            w, CAPACITY, use_pallas=True)
+        shapes = (((nodes, compression.bitset_words(CAPACITY)), jnp.uint32),)
+    elif stage == "ef_encode":
+        fn = lambda b, m: wire_codec.ef_encode(  # noqa: E731
+            b, m, domain, use_pallas=True)
+        shapes = ((rows, jnp.int32), (rows, jnp.bool_))
+    else:
+        fn = lambda w: wire_codec.ef_decode(  # noqa: E731
+            w, CAPACITY, domain, jnp.int32(0), use_pallas=True)
+        words = compression.packed_request_words(CAPACITY, domain)
+        shapes = (((nodes, words), jnp.uint32),)
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)

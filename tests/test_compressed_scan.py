@@ -331,3 +331,60 @@ def test_resident_budget_guard(cluster):
 
     with pytest.raises(ResidentBudgetError, match="resident"):
         TPCHDriver(sf=0.01, cluster=cluster, seed=0, resident_budget=1024)
+
+
+# -- scan-roofline rates by device kind ------------------------------------
+
+
+def test_scan_calibration_table_names_its_sources():
+    from repro.core import scancal
+
+    for kind, cal in scancal.TABLE.items():
+        assert cal.source, kind
+    assert scancal.for_device("cpu") is scancal.TABLE["cpu"]
+    with pytest.raises(scancal.ScanCalError, match="TPU v99"):
+        scancal.for_device("TPU v99")
+
+
+def test_lowering_takes_rates_from_the_catalog_device_kind(tpch_driver):
+    """An uncalibrated device kind is an error at lowering, not a silent
+    default; the catalog carries the kind of the driver's devices."""
+    from repro.core import scancal
+    from repro.query import lower
+    from repro.tpch import queries as tq
+
+    assert tpch_driver.catalog.device_kind == "cpu"
+    cat = dataclasses.replace(tpch_driver.catalog, device_kind="TPU v99")
+    with pytest.raises(scancal.ScanCalError):
+        lower(tq.PARAM_QUERIES["q6"](), cat)
+
+
+def test_generated_data_is_pinned_by_the_seed():
+    """A seed names one dataset in every process: these sums were taken in
+    other processes (Python's string hash, salted per process, no longer
+    keys the per-table streams)."""
+    from repro.tpch import dbgen
+
+    t = dbgen.generate(0.001, 2, 3)
+    got = (int(np.asarray(t["lineitem"].columns["l_shipdate"]).sum()),
+           int(np.asarray(t["orders"].columns["o_custkey"]).sum()),
+           int(np.asarray(t["part"].columns["p_type"]).sum()))
+    assert got == (7700888, 109305, 15029)
+
+
+def test_driver_plans_scan_packed_columns(tpch_driver, monkeypatch):
+    """A query the driver lowers filters packed columns with the scan
+    kernel: the compiled plan receives the packed words, not columns
+    decoded at plan entry."""
+    from repro.query import Q
+
+    calls = []
+    real = ops.scan_filter
+    monkeypatch.setattr(ops, "scan_filter",
+                        lambda *a, **k: calls.append(k["width"]) or real(*a, **k))
+    q = (Q.scan("lineitem").filter(C("l_quantity") < 17.0)
+         .group_agg(aggs=[("n", "count")]).named("packed_probe"))
+    ans = tpch_driver.query(q)
+    assert calls, "the lowered plan decoded l_quantity instead of scanning it"
+    want = (tpch_driver.tables["lineitem"].columns["l_quantity"] < 17.0).sum()
+    assert float(np.asarray(ans.value).reshape(())) == float(want)

@@ -374,3 +374,60 @@ def test_late_materialization(cluster):
                jnp.asarray(valid), replicated_args=(1, 2))
     np.testing.assert_array_equal(out["attr"][:4], col[win_keys[:4]])
     np.testing.assert_array_equal(out["attr"][4:], 0)
+
+
+def _sorted_topk(values, keys, mask, k):
+    order = topk._rank_order(jnp.asarray(values), jnp.asarray(keys),
+                             jnp.asarray(mask))[:k]
+    return (np.where(mask[order], values[order], -np.inf), keys[order],
+            mask[order])
+
+
+@pytest.mark.parametrize("case", ["distinct", "ties", "all_equal",
+                                  "few_valid", "negative"])
+def test_local_topk_select_matches_full_sort(case):
+    """Large partitions select top-k candidates without sorting the
+    partition; the answer must equal the full sort's, ties included."""
+    rng = np.random.default_rng(len(case))
+    n, k = topk.SELECT_MIN_ROWS + 37, 100
+    values = {
+        "distinct": rng.permutation(n).astype(np.float32),
+        "ties": rng.integers(0, 40, n).astype(np.float32),
+        "all_equal": np.full(n, 3.0, np.float32),
+        "few_valid": rng.random(n).astype(np.float32),
+        "negative": -rng.integers(0, 40, n).astype(np.float32),
+    }[case]
+    keys = rng.permutation(n).astype(np.int32)
+    mask = rng.random(n) < (0.0005 if case == "few_valid" else 0.5)
+    got = jax.jit(lambda v, kk, m: topk.local_topk(v, kk, k, m))(
+        values, keys, mask)
+    want_v, want_k, want_m = _sorted_topk(values, keys, mask, k)
+    np.testing.assert_array_equal(np.asarray(got.valid), want_m)
+    np.testing.assert_array_equal(np.asarray(got.values), want_v)
+    nv = int(want_m.sum())
+    np.testing.assert_array_equal(np.asarray(got.keys)[:nv], want_k[:nv])
+
+
+def test_first_true_left_packs_in_order():
+    mask = np.array([0, 1, 1, 0, 0, 1, 0], bool)
+    idx, ok = topk.first_true(jnp.asarray(mask), 5)
+    np.testing.assert_array_equal(np.asarray(ok), [1, 1, 1, 0, 0])
+    np.testing.assert_array_equal(np.asarray(idx)[:3], [1, 2, 5])
+
+
+@pytest.mark.parametrize("n", [64, 1000, 1027])
+def test_group_sums_over_row_blocks_match_numpy(monkeypatch, n):
+    """One-hot and mask-GEMM sums contract rows in blocks, the last one
+    clamped; any block size gives the exact group sums."""
+    from repro.core import aggregation
+
+    monkeypatch.setattr(aggregation, "SUM_BLOCK_ROWS", 96)
+    rng = np.random.default_rng(n)
+    vals = rng.integers(0, 100, (n, 3)).astype(np.float32)
+    gid = rng.integers(0, 7, n).astype(np.int32)   # code 6 is out of range
+    mask = rng.random(n) < 0.6
+    want = np.zeros((6, 3))
+    np.add.at(want, gid[mask & (gid < 6)], vals[mask & (gid < 6)])
+    for fn in (aggregation.group_sum_maskgemm, aggregation.group_sum_onehot):
+        got = fn(jnp.asarray(vals), jnp.asarray(gid), 6, jnp.asarray(mask))
+        np.testing.assert_array_equal(np.asarray(got), want)

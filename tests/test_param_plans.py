@@ -214,6 +214,24 @@ def test_batch_lanes_bitwise_equal_scalar_executes(tpch_driver):
         assert batched[i].tobytes() == np.asarray(scalar["value"]).tobytes()
 
 
+@pytest.mark.parametrize("name", ["q1", "q6", "q14_promo"])
+def test_batch_lanes_loop_on_large_partitions(cluster, monkeypatch, name):
+    """Past ``BATCH_VMAP_MAX_ROWS`` rows per node a batched plan runs its
+    lanes one after another in the same dispatch; every lane must still
+    match the oracle for its own binding."""
+    from repro.core import engine
+
+    monkeypatch.setattr(engine, "BATCH_VMAP_MAX_ROWS", 0)
+    driver = TPCHDriver(sf=0.005, cluster=cluster, seed=0)
+    prep = driver.prepare(tq.PARAM_QUERIES[name]())
+    rng = np.random.default_rng(7)
+    bindings = [tq.random_binding(name, rng) for _ in range(3)]
+    ans = prep.execute_batch(bindings)
+    assert not np.asarray(ans.overflow).any()
+    for i, b in enumerate(bindings):
+        _check(name, np.asarray(ans.value)[i], _oracle(name, driver, b))
+
+
 # ---------------------------------------------------------------------------
 # plan-cache regression: key modulo parameter values, not modulo structure
 # ---------------------------------------------------------------------------
