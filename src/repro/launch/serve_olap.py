@@ -18,8 +18,10 @@ picks a closed loop (N clients back-to-back); ``--rate QPS`` an open loop
 (Poisson arrivals).
 
 --metrics dumps the driver's metrics registry (tier counters, plan-cache
-hit/miss, latency histograms) on exit; --trace PATH writes the structured
-trace as Chrome-trace JSON loadable in https://ui.perfetto.dev.
+hit/miss, latency histograms) on exit; --trace DIR records a
+``jax.profiler`` trace of the serving session under DIR: the driver's
+spans and the devices' operations on one clock, as ``.xplane.pb`` (xprof,
+TensorBoard) and ``perfetto_trace.json.gz`` (https://ui.perfetto.dev).
 """
 from __future__ import annotations
 
@@ -198,9 +200,9 @@ def main(argv=None):
                         "waited this long")
     p.add_argument("--metrics", action="store_true",
                    help="print the driver's metrics-registry report on exit")
-    p.add_argument("--trace", metavar="PATH", default=None,
-                   help="write the structured trace as Chrome-trace JSON "
-                        "(loadable in Perfetto) on exit")
+    p.add_argument("--trace", metavar="DIR", default=None,
+                   help="record a jax.profiler trace of the session under "
+                        "DIR (xplane.pb and perfetto_trace.json.gz)")
     args = p.parse_args(argv)
 
     import jax
@@ -224,6 +226,8 @@ def main(argv=None):
 
     compile_cache.enable()
     d = TPCHDriver(sf=args.sf, seed=args.seed, backend=args.backend)
+    if args.trace:
+        jax.profiler.start_trace(args.trace, create_perfetto_trace=True)
     try:
         if args.lint:
             print(f"cluster: {d.cluster.num_nodes} nodes | SF {args.sf} | "
@@ -267,7 +271,8 @@ def main(argv=None):
         if args.metrics:
             print("\n" + d.obs.metrics.report())
         if args.trace:
-            print(f"\ntrace written to {d.obs.save_chrome_trace(args.trace)}")
+            jax.profiler.stop_trace()
+            print(f"\ntrace written under {args.trace}")
 
 
 if __name__ == "__main__":

@@ -1,7 +1,8 @@
 """Runtime observability: structured traces, a metrics registry, and
 EXPLAIN/EXPLAIN ANALYZE rendering.
 
-  trace    nested spans + Chrome-trace/Perfetto export (``Observer``)
+  trace    nested spans on the profiler's clock (``Observer``) and layer
+           scopes for lowered plans (``layer``)
   metrics  named counters/gauges/log-bucketed histograms with p50/p95/p99
   explain  plan rendering with predicted-vs-observed fields
 
@@ -21,4 +22,4 @@ from repro.obs.metrics import (  # noqa: F401
     Histogram,
     MetricsRegistry,
 )
-from repro.obs.trace import Observer, Span  # noqa: F401
+from repro.obs.trace import Observer, Span, layer  # noqa: F401

@@ -1,23 +1,30 @@
-"""Structured trace layer: nested spans, Chrome-trace export, text render.
+"""Structured trace layer: nested spans on the profiler's clock, layer
+scopes for lowered plans, text render.
 
 One :class:`Observer` object is threaded through the engine (driver,
 router, lowering, exchange layer) instead of a global — tests construct
 their own and assert on the exact spans a code path emitted.  A span is a
 named, timed interval with attributes and children; an event is an
 instant (zero-duration) child.  The driver records per-query spans (route
-decision, plan-cache hit/miss, compile vs execute), the lowering records
-its semi-join decisions, and the exchange layer emits one trace-time
-event per collective exchange (fired during the XLA trace, i.e. once per
-compiled specialization — static shapes, capacities and wire formats).
+decision, plan-cache hit/miss, bind / dispatch / fetch), the lowering
+records its semi-join decisions, and the exchange layer emits one
+trace-time event per collective exchange (fired during the XLA trace,
+i.e. once per compiled specialization — static shapes, capacities and
+wire formats).
 
-Export targets:
+Export: every ``with obs.span(...)`` also opens a
+``jax.profiler.TraceAnnotation`` for the span's lifetime, which is a
+no-op unless a profiler session is active.  While one is (``jax.profiler
+.trace(dir, create_perfetto_trace=True)``), the spans land in its
+``.xplane.pb`` on the same clock as the device's operations, and in its
+``perfetto_trace.json.gz``, which https://ui.perfetto.dev loads.
+Instant events and manually managed spans (:meth:`Observer.open_span`)
+stay in the span tree only.  :meth:`Observer.pretty` renders the tree as
+indented text for terminals and tests.
 
-- :meth:`Observer.to_chrome_trace` — the Chrome trace-event JSON dict
-  (``{"traceEvents": [...]}``; complete-``X`` spans, instant-``i``
-  events, microsecond timestamps) that https://ui.perfetto.dev and
-  ``chrome://tracing`` load directly; :meth:`Observer.save_chrome_trace`
-  writes it to a file.
-- :meth:`Observer.pretty` — an indented text tree for terminals/tests.
+:func:`layer` names the engine layer (``scan``, ``semijoin``,
+``aggregate``, ``topk``) of the operations a lowered plan emits, in the
+``op_name`` metadata of each compiled operation.
 
 A disabled observer (``enabled=False``) swallows everything through a
 shared null span, so instrumented code paths need no ``if`` guards; the
@@ -27,11 +34,12 @@ object (``obs.metrics``) so every instrumented site can emit both.
 from __future__ import annotations
 
 import dataclasses
-import json
 import threading
 import time
 from collections import deque
 from typing import Optional
+
+import jax
 
 from repro.obs.metrics import MetricsRegistry
 
@@ -40,11 +48,26 @@ from repro.obs.metrics import MetricsRegistry
 MAX_ROOT_SPANS = 1024
 
 
+def layer(name: str):
+    """``with layer("scan"): ...`` — a ``jax.named_scope`` naming the engine
+    layer of the operations traced inside it; the name becomes the first
+    component of their ``op_name`` under the jitted plan
+    (``jit(run)/shard_map/scan/...``), in the compiled program's metadata
+    and in a profiler trace's ``tf_op`` stat.  Only metadata changes: the
+    compiled program is the same with or without it.
+
+    The lowering opens one per operator, after the operator's child has
+    been evaluated, so layers never nest.  A packed column that is
+    decoded lazily (on first touch) takes the layer of the operator that
+    first reads it."""
+    return jax.named_scope(name)
+
+
 @dataclasses.dataclass
 class Span:
     """One timed interval.  ``t0``/``dur`` are seconds relative to the
-    observer's epoch; attributes are plain data (they land in the Chrome
-    trace ``args`` field verbatim)."""
+    observer's epoch; attributes are plain data (the plain scalars among
+    them become the profiler annotation's arguments)."""
 
     name: str
     cat: str = "query"
@@ -89,19 +112,25 @@ _NULL_SPAN = _NullSpan()
 
 
 class _SpanContext:
-    """Context manager driving one live span on the observer's stack."""
+    """Context manager driving one live span on the observer's stack and a
+    profiler annotation of the same name for its lifetime."""
 
-    __slots__ = ("obs", "span")
+    __slots__ = ("obs", "span", "annotation")
 
     def __init__(self, obs: "Observer", span: Span):
         self.obs = obs
         self.span = span
+        self.annotation = jax.profiler.TraceAnnotation(
+            span.name, **{k: v for k, v in span.attrs.items()
+                          if isinstance(v, (int, float, str, bool))})
 
     def __enter__(self) -> Span:
         self.obs._stack.append(self.span)
+        self.annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb):
+        self.annotation.__exit__(exc_type, exc, tb)
         span = self.obs._stack.pop()
         span.dur = self.obs._now() - span.t0
         if exc_type is not None:
@@ -202,46 +231,7 @@ class Observer:
         hits = self.find(name)
         return hits[-1] if hits else None
 
-    # -- export -------------------------------------------------------------
-    def to_chrome_trace(self) -> dict:
-        """Chrome trace-event JSON (loads in Perfetto / chrome://tracing)."""
-        events = []
-
-        def _emit(span: Span, tid: int):
-            e = {
-                "name": span.name,
-                "cat": span.cat,
-                "ts": span.t0 * 1e6,
-                "pid": 1,
-                "tid": tid,
-                "args": _plain(span.attrs),
-            }
-            if span.instant:
-                e.update(ph="i", s="t")
-            else:
-                e.update(ph="X", dur=span.dur * 1e6)
-            events.append(e)
-            for c in span.children:
-                _emit(c, tid)
-
-        for root in self.spans:
-            _emit(root, tid=1)
-        return {
-            "traceEvents": events,
-            "displayTimeUnit": "ms",
-            "otherData": {"source": "repro.obs"},
-        }
-
-    def save_chrome_trace(self, path: str) -> str:
-        import os
-
-        d = os.path.dirname(path)
-        if d:
-            os.makedirs(d, exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(self.to_chrome_trace(), f, indent=1)
-        return path
-
+    # -- rendering ---------------------------------------------------------
     def pretty(self) -> str:
         """Indented text rendering of every retained root span."""
         lines = []
@@ -265,15 +255,3 @@ class Observer:
         for root in self.spans:
             _walk(root, 0)
         return "\n".join(lines)
-
-
-def _plain(attrs: dict) -> dict:
-    """JSON-safe attribute dict (numpy scalars -> python, objects -> str)."""
-    out = {}
-    for k, v in attrs.items():
-        if hasattr(v, "item") and callable(v.item) and getattr(v, "ndim", 1) == 0:
-            v = v.item()
-        if not isinstance(v, (int, float, str, bool, type(None))):
-            v = str(v)
-        out[k] = v
-    return out

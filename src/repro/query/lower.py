@@ -21,6 +21,15 @@ Physical mapping:
 - ``TopK``                 -> per-node top-k + §3.2.3 merging reduction,
   late-materializing fetch attributes (§3.2.7)
 
+Each operator's own work runs in the :func:`repro.obs.layer` scope of
+its engine layer, opened after its child is evaluated, so the compiled
+operations carry at most one layer in their ``op_name``: ``scan``
+(``Filter``, with the packed scan kernel and bitset unpack, and
+``Project``), ``semijoin`` (``SemiJoin``, all alternatives, and
+``Exists``), ``aggregate`` (``GroupAggByKey`` and the ``GroupAgg`` root
+with its ``psum``) and ``topk`` (the ``TopK`` root with its merging
+reduction and late materialization).
+
 Lowered plans return a dict: ``{"value"}`` for ``GroupAgg`` roots,
 ``{"values", "keys", "valid", <fetched attrs>}`` for ``TopK`` roots.  When
 (and only when) the plan contains a request exchange, an ``"overflow"``
@@ -47,6 +56,7 @@ from repro.core.columnar import PackedColumn
 from repro.core.compression import choose_semijoin_wire
 from repro.core.engine import vmaps_lanes
 from repro.core.exchange import WireFormat
+from repro.obs.trace import layer
 from repro.query import stats as qstats
 from repro.query.ir import (
     Bin,
@@ -75,6 +85,10 @@ from repro.query.ir import (
 
 ONEHOT_MAX_GROUPS = 8192
 KERNEL_MAX_GROUPS = 512
+
+# the engine layer (repro.obs.layer scope) of each non-root operator
+_LAYERS = {Filter: "scan", Project: "scan", SemiJoin: "semijoin",
+           Exists: "semijoin", GroupAggByKey: "aggregate"}
 
 
 # ---------------------------------------------------------------------------
@@ -509,7 +523,13 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                            mask=None, overflow=False)
 
         s = _eval(node.child, ctx, t, pv)
+        if type(node) not in _LAYERS:
+            raise LoweringError(f"cannot lower operator {type(node).__name__}")
+        with layer(_LAYERS[type(node)]):
+            return _apply(node, s, ctx, t, pv)
 
+    def _apply(node, s, ctx, t, pv) -> _Stream:
+        """One operator's own work on its child's stream ``s``."""
         if isinstance(node, Filter):
             per = scan_plans.get(id(node))
             if per is None:
@@ -616,14 +636,17 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
             return _Stream(base=node.into, cols=cols, mask=None,
                            overflow=s.overflow)
 
-        raise LoweringError(f"cannot lower operator {type(node).__name__}")
-
     def _run(ctx, t, pv):
+        s = _eval(root.child, ctx, t, pv)
+        with layer("aggregate" if isinstance(root, GroupAgg) else "topk"):
+            return _root(s, ctx, t, pv)
+
+    def _root(s: _Stream, ctx, t, pv):
+        """The root's own work (grouped aggregate or top-k) on ``s``."""
         if isinstance(root, GroupAgg):
             if root.method == "kernel":
                 from repro.kernels import ops
 
-                s = _eval(root.child, ctx, t, pv)
                 gid = _group_ids(root, s, pv, clip=True)  # kernel indexes by gid
                 stacked = _measure_stack(root.aggs, s.cols, mask=None, pv=pv)
                 local = ops.filtered_group_sum(
@@ -631,7 +654,6 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                     cutoff=kernel_cutoff, num_groups=num_groups,
                 )
             else:
-                s = _eval(root.child, ctx, t, pv)
                 method = root.method
                 if method == "auto":
                     method = "onehot" if num_groups <= ONEHOT_MAX_GROUPS else "dense"
@@ -680,7 +702,6 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
             return out
 
         # TopK root
-        s = _eval(root.child, ctx, t, pv)
         if root.pred is not None:
             s.and_mask(eval_expr(root.pred, s.cols, pv))
         values = eval_expr(root.value, s.cols, pv)

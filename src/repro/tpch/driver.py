@@ -31,7 +31,9 @@ surfaces any binding that exceeds them.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
 import os
 import threading
 import time
@@ -160,6 +162,14 @@ class PreparedQuery:
     the shape is matched once, but bin-edge exactness is re-checked per
     binding, falling back to the compiled Tier-2 plan for off-edge or
     out-of-range values.
+
+    Each execution records a root span (``query`` / ``query.batch``,
+    carrying the driver's request number) whose Tier-2 children are
+    ``bind`` (cast the binding to device scalars and transfer them),
+    ``dispatch`` (``_guarded_call``, with any wait at the dispatch gate
+    and, on a specialization's first call, its trace and compile) and
+    ``fetch`` (``jax.device_get`` of the result); a single execution's
+    ``route`` probe precedes ``bind``.
     """
 
     def __init__(self, driver: "TPCHDriver", entry: _PlanEntry,
@@ -264,7 +274,8 @@ class PreparedQuery:
         mreg = obs.metrics
         t_start = time.perf_counter()
         with obs.span("query", source=self.source,
-                      cache="hit" if self.cache_hit else "miss") as sp:
+                      cache="hit" if self.cache_hit else "miss",
+                      request=self.driver._next_request()) as sp:
             b = self.binding(params)
             with obs.span("route", cat="route"):
                 ans = self._tier1(b)
@@ -275,14 +286,14 @@ class PreparedQuery:
                     (time.perf_counter() - t_start) * 1e6)
                 return ans
             fn = self._tier2_fn()
-            cols = self.driver._columns()
-            with obs.span("execute", cat="exec"):
+            with obs.span("bind", cat="exec"):
+                args = [self.driver._columns()]
                 if self.entry.params:
-                    out = self.driver._guarded_call(
-                        self.entry, "scalar", fn, cols, self._cast(b))
-                else:
-                    out = self.driver._guarded_call(
-                        self.entry, "scalar", fn, cols)
+                    args.append(self._cast(b))
+            with obs.span("dispatch", cat="exec"):
+                out = self.driver._guarded_call(
+                    self.entry, "scalar", fn, *args)
+            with obs.span("fetch", cat="exec"):
                 out = jax.device_get(out)
             overflow = bool(np.asarray(out.pop("overflow", False)))
             value = out["value"] if set(out) == {"value"} else out
@@ -332,27 +343,30 @@ class PreparedQuery:
             B = len(rows)
         if B == 0:
             raise QueryError("execute_batch needs at least one binding")
-        merged = [self.binding(r) for r in rows]
         obs = self.driver.obs
         mreg = obs.metrics
-        lanes = B
-        if pad_to is not None and pad_to > B:
-            merged = merged + [merged[-1]] * (pad_to - B)
-            lanes = pad_to
-            mreg.counter("driver.batch_pad_lanes").inc(pad_to - B)
-        stacked = {
-            p.name: jnp.asarray(np.asarray([m[p.name] for m in merged],
-                                           np.dtype(p.dtype)))
-            for p in self.entry.params
-        }
+        lanes = max(B, pad_to or 0)
         with obs.span("query.batch", source=self.source, lanes=B,
-                      padded=lanes) as sp:
+                      padded=lanes,
+                      request=self.driver._next_request()) as sp:
+            with obs.span("bind", cat="exec"):
+                merged = [self.binding(r) for r in rows]
+                if lanes > B:
+                    merged = merged + [merged[-1]] * (lanes - B)
+                    mreg.counter("driver.batch_pad_lanes").inc(lanes - B)
+                stacked = {
+                    p.name: jnp.asarray(np.asarray(
+                        [m[p.name] for m in merged], np.dtype(p.dtype)))
+                    for p in self.entry.params
+                }
             self._tier2_fn()  # surface LoweringError as UncoveredQueryError
             fn = self.driver._ensure_batched(self.entry)
-            with obs.span("execute", cat="exec"):
-                out = jax.device_get(self.driver._guarded_call(
+            with obs.span("dispatch", cat="exec"):
+                out = self.driver._guarded_call(
                     self.entry, ("batch", lanes), fn,
-                    self.driver._columns(), stacked))
+                    self.driver._columns(), stacked)
+            with obs.span("fetch", cat="exec"):
+                out = jax.device_get(out)
             overflow = out.pop("overflow", None)
             overflow = (np.zeros(lanes, bool) if overflow is None
                         else np.asarray(overflow))
@@ -372,10 +386,38 @@ class PreparedQuery:
 
 
 class TPCHDriver:
+    """One TPC-H instance resident on a cluster (see the module docstring).
+
+    ``load_seconds`` is the set-up timer: host seconds of each set-up step
+    the driver runs, keyed by step.  The steps are disjoint, so their sum
+    is the driver's share of set-up:
+
+    - ``generate``, ``pack``, ``place``, ``catalog``: the data generator,
+      column packing, placement on the devices (until the arrays are
+      ready) and the catalog statistics, at construction;
+    - ``compile``: summed over every prepared shape and specialization,
+      lowering and jit-wrapping a shape (``_ensure_compiled`` /
+      ``_ensure_batched``) plus the first dispatch of each
+      specialization, which traces it and compiles it (or loads it from
+      the persistent cache); added on first use;
+    - ``cubes``: ``build_cubes``, its compiles included.
+
+    Steps run from several threads (the serving tier warms shapes
+    concurrently) add thread-seconds."""
+
     def __init__(self, sf: float, cluster: Cluster | None = None, seed: int = 0,
                  capacities=None, backend: str = "xla", wire: str = "packed",
                  obs: Observer | None = None, storage: str = "packed",
                  resident_budget: Optional[int] = None):
+        # one lock for every cache the driver mutates (_compiled,
+        # _prepared + its LRU order, per-entry bound-closure LRUs) and for
+        # load_seconds: the serving tier calls prepare()/query() from the
+        # event loop and executor threads concurrently.  Reentrant because
+        # prepare() is reached from compile()/compile_query() which may
+        # already hold it.
+        self._lock = threading.RLock()
+        self.load_seconds = {}
+        self._request_ids = itertools.count(1)  # root spans' request numbers
         self.cluster = cluster or Cluster()
         self.sf = sf
         self.seed = seed
@@ -396,16 +438,12 @@ class TPCHDriver:
         # stats; self.resident is what the cluster holds and places — with
         # storage="packed", eligible columns in the compressed PackedColumn
         # form (lossless, so the host view is bit-identical to the codes).
-        # load_seconds: host seconds of each load step (set-up, not query)
-        self.load_seconds = {}
-        t0 = time.perf_counter()
-        self.tables = dbgen.generate(sf, self.cluster.num_nodes, seed)
-        self.load_seconds["generate"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        self.resident = (dbgen.pack_tables(self.tables,
-                                           self.cluster.num_nodes)
-                         if storage == "packed" else dict(self.tables))
-        self.load_seconds["pack"] = time.perf_counter() - t0
+        with self._setup_step("generate"):
+            self.tables = dbgen.generate(sf, self.cluster.num_nodes, seed)
+        with self._setup_step("pack"):
+            self.resident = (dbgen.pack_tables(self.tables,
+                                               self.cluster.num_nodes)
+                             if storage == "packed" else dict(self.tables))
         # pad the supplier key space so §3.2.5 groups divide evenly
         self._extend_derived_tables()
         for extra in set(self.tables) - set(self.resident):
@@ -417,10 +455,11 @@ class TPCHDriver:
                 if isinstance(col, PackedColumn)}
             for n, t in self.resident.items()
         }
-        self.catalog = build_catalog(self.tables,
-                                     num_nodes=self.cluster.num_nodes,
-                                     packed=packed_meta,
-                                     device_kind=self.cluster.device_kind)
+        with self._setup_step("catalog"):
+            self.catalog = build_catalog(self.tables,
+                                         num_nodes=self.cluster.num_nodes,
+                                         packed=packed_meta,
+                                         device_kind=self.cluster.device_kind)
         # resident-footprint accounting + node memory budget: the budget
         # models per-node main memory; exceeding it is the OOM the packed
         # format exists to push out by ~the compression ratio
@@ -442,11 +481,10 @@ class TPCHDriver:
                 f"{storage!r} storage but the node budget is "
                 f"{resident_budget} bytes (fully decoded it would be "
                 f"{raw}); use storage='packed' or a smaller scale factor")
-        t0 = time.perf_counter()
-        self.placed = {n: self.cluster.load(t)
-                       for n, t in self.resident.items()}
-        jax.block_until_ready([t.columns for t in self.placed.values()])
-        self.load_seconds["place"] = time.perf_counter() - t0
+        with self._setup_step("place"):
+            self.placed = {n: self.cluster.load(t)
+                           for n, t in self.resident.items()}
+            jax.block_until_ready([t.columns for t in self.placed.values()])
         self.ctx = self.cluster.context(
             self.placed, self.capacities, backend=backend, scale_factor=sf,
             wire=wire,
@@ -456,12 +494,6 @@ class TPCHDriver:
         )
         self._compiled = {}       # registry name -> compiled hand plan
         self._prepared = {}       # STRUCTURAL shape key -> _PlanEntry (LRU)
-        # one lock for every cache the driver mutates (_compiled,
-        # _prepared + its LRU order, per-entry bound-closure LRUs): the
-        # serving tier calls prepare()/query() from the event loop and
-        # executor threads concurrently.  Reentrant because prepare() is
-        # reached from compile()/compile_query() which may already hold it.
-        self._lock = threading.RLock()
         # Device executions are globally serialized: XLA's host-platform
         # collectives rendezvous on the 8 shared device threads, so TWO
         # multi-device programs dispatched concurrently each wait for all
@@ -494,6 +526,22 @@ class TPCHDriver:
     def _columns(self):
         return {n: t.columns for n, t in self.placed.items()}
 
+    def _add_setup_seconds(self, step: str, seconds: float) -> None:
+        with self._lock:
+            self.load_seconds[step] = self.load_seconds.get(step, 0.0) + seconds
+
+    @contextlib.contextmanager
+    def _setup_step(self, step: str):
+        """Add the block's host seconds to ``load_seconds[step]``."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self._add_setup_seconds(step, time.perf_counter() - t0)
+
+    def _next_request(self) -> int:
+        return next(self._request_ids)
+
     def _count_scan_bytes(self, entry: _PlanEntry, lanes: int = 1) -> None:
         """Account one execution's predicted scan traffic against the
         ``storage.bytes_scanned`` counters (cluster-wide bytes: per-node
@@ -517,15 +565,21 @@ class TPCHDriver:
         one thread pays the deferred XLA trace, and EVERY call holds the
         driver's ``_dispatch_gate`` so two collective programs never
         rendezvous concurrently on the shared host-platform devices (see
-        the gate's comment in ``__init__``)."""
+        the gate's comment in ``__init__``).  The first call's time, gate
+        wait excluded, is set-up (``load_seconds["compile"]``)."""
         if key in entry.warm:
             with self._dispatch_gate:
                 return fn(*args)
         with entry.lock:
+            cold = key not in entry.warm
             with self._dispatch_gate:
+                t0 = time.perf_counter()
                 out = fn(*args)
+                seconds = time.perf_counter() - t0
             entry.warm.add(key)
-            return out
+        if cold:  # added outside entry.lock, which nests inside _lock
+            self._add_setup_seconds("compile", seconds)
+        return out
 
     # -- physical layer (hand plans / lowered IR by registry name) ---------
     def compile(self, name: str):
@@ -656,24 +710,32 @@ class TPCHDriver:
 
     def _ensure_compiled(self, entry: _PlanEntry):
         if entry.fn is None:
+            seconds = 0.0
             with entry.lock:  # double-checked: lower+jit-wrap once
                 if entry.fn is None:
+                    t0 = time.perf_counter()
                     label = entry.shape.name or "<lowered-ir>"
                     with self.obs.span("lower", cat="plan", label=label):
                         entry.fn = self.cluster.compile(
                             self._lowered_plan(entry, label),
                             self.ctx, self.placed)
+                    seconds = time.perf_counter() - t0
+            self._add_setup_seconds("compile", seconds)
         return entry.fn
 
     def _ensure_batched(self, entry: _PlanEntry):
         if entry.batched_fn is None:
+            seconds = 0.0
             with entry.lock:
                 if entry.batched_fn is None:
+                    t0 = time.perf_counter()
                     label = f"{entry.shape.name or '<lowered-ir>'}@batch"
                     with self.obs.span("lower", cat="plan", label=label):
                         entry.batched_fn = self.cluster.compile(
                             self._lowered_plan(entry, label, batched=True),
                             self.ctx, self.placed, batch=True)
+                    seconds = time.perf_counter() - t0
+            self._add_setup_seconds("compile", seconds)
         return entry.batched_fn
 
     def compile_query(self, q: Query):
@@ -713,7 +775,8 @@ class TPCHDriver:
 
             specs = tpch_cubes.default_specs()
         for spec in specs:
-            with self.obs.span("cube.build", cat="plan", cube=spec.name):
+            with (self.obs.span("cube.build", cat="plan", cube=spec.name),
+                  self._setup_step("cubes")):
                 self.cubes[spec.name] = build_cube(
                     self.cluster, self.ctx, self.placed, spec
                 )

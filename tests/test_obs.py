@@ -3,9 +3,13 @@ EXPLAIN ANALYZE.
 
 - histogram percentiles on fixed distributions with known quantiles (the
   log-bucket scheme guarantees ~2.2% relative error),
-- span nesting + Chrome-trace export round-trip (valid trace-event JSON
-  with complete/instant phases — the shape Perfetto loads), and a sample
-  trace artifact written for CI,
+- span nesting, and the spans in a ``jax.profiler`` trace on the
+  profiler's clock: its Chrome trace-event JSON (the shape Perfetto loads)
+  round-trips, the driver's ``query`` span holds ``bind`` / ``dispatch`` /
+  ``fetch``, and a sample trace artifact is written for CI,
+- the driver's set-up timers (``load_seconds``) and the layer scopes of
+  lowered plans (``op_name`` metadata that leaves the compiled program as
+  it is),
 - ``explain_analyze`` golden checks on q6 (predicted plan fields next to
   observed timings/counters) and on a Tier-1 cube-served query,
 - per-semijoin all-to-all attribution against synthetic instruction
@@ -15,9 +19,18 @@ EXPLAIN ANALYZE.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
+import glob
+import gzip
+import importlib
 import json
+import os
+import re
+import shutil
 
+import jax
 import pytest
 
 from repro.launch.roofline import CollectiveInstr
@@ -29,6 +42,7 @@ from repro.obs import (
     attribute_semijoin_bytes,
 )
 from repro.query import Q, C
+from repro.tpch.driver import _PlanEntry
 
 pytestmark = pytest.mark.tier1
 
@@ -92,13 +106,50 @@ def test_registry_counters_gauges_and_report():
 # ---------------------------------------------------------------------------
 
 
+def _profile_file(log_dir, pattern: str) -> str:
+    """The newest file of a ``jax.profiler`` trace under ``log_dir``."""
+    paths = sorted(glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                          "*", pattern)))
+    assert paths, f"no {pattern} under {log_dir}"
+    return paths[-1]
+
+
+def _chrome_events(log_dir, names) -> dict:
+    """name -> its complete/instant events in the trace's Chrome JSON."""
+    with gzip.open(_profile_file(log_dir, "perfetto_trace.json.gz")) as f:
+        doc = json.load(f)  # round-trip through disk
+    out = collections.defaultdict(list)
+    for e in doc["traceEvents"]:
+        if e.get("name") in names:
+            out[e["name"]].append(e)
+    return out
+
+
+def _host_spans(log_dir, names) -> dict:
+    """name -> [(start_ns, end_ns)] of the host events in the .xplane.pb."""
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(_profile_file(log_dir, "*.xplane.pb"))
+    out = collections.defaultdict(list)
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    s0 = int(e.start_ns)
+                    out[e.name].append((s0, s0 + int(e.duration_ns)))
+    return out
+
+
 def test_span_nesting_and_chrome_export_roundtrip(tmp_path):
     obs = Observer()
-    with obs.span("query", source="qX") as sp:
-        sp.set(tier=2)
-        with obs.span("route", cat="route"):
-            pass
-        obs.event("xla.trace", cat="plan", label="qX")
+    with jax.profiler.trace(str(tmp_path), create_perfetto_trace=True):
+        with obs.span("query", source="qX", request=7) as sp:
+            sp.set(tier=2)
+            with obs.span("route", cat="route"):
+                pass
+            obs.event("xla.trace", cat="plan", label="qX")
     roots = list(obs.spans)
     assert len(roots) == 1
     root = roots[0]
@@ -106,19 +157,16 @@ def test_span_nesting_and_chrome_export_roundtrip(tmp_path):
     assert root.attrs["tier"] == 2
     assert root.dur >= root.children[0].dur >= 0
 
-    path = obs.save_chrome_trace(str(tmp_path / "trace.json"))
-    doc = json.loads(open(path).read())  # round-trip through disk
-    events = doc["traceEvents"]
-    assert {e["name"] for e in events} == {"query", "route", "xla.trace"}
-    for e in events:
-        assert e["ph"] in ("X", "i")
+    # the spans, and no instant event, in the profiler's Chrome trace JSON
+    events = _chrome_events(tmp_path, {"query", "route", "xla.trace"})
+    assert set(events) == {"query", "route"}
+    (q,), (r,) = events["query"], events["route"]
+    for e in (q, r):
+        assert e["ph"] == "X" and e["dur"] >= 0.0
         assert isinstance(e["ts"], float) and "pid" in e and "tid" in e
-        if e["ph"] == "X":
-            assert e["dur"] >= 0.0
-    q = next(e for e in events if e["name"] == "query")
-    r = next(e for e in events if e["name"] == "route")
-    assert q["ts"] <= r["ts"] <= q["ts"] + q["dur"]  # child inside parent
-    assert q["args"]["tier"] == 2
+    assert q["ts"] <= r["ts"] <= r["ts"] + r["dur"] <= q["ts"] + q["dur"]
+    # plain attributes given at open become the annotation's arguments
+    assert q["args"]["source"] == "qX" and q["args"]["request"] == "7"
 
 
 def test_disabled_observer_swallows_spans_keeps_metrics():
@@ -293,16 +341,113 @@ def test_driver_counters_and_spans(tpch_driver):
 
 
 def test_sample_trace_artifact(tpch_driver):
-    """Write the CI trace artifact (uploaded by the workflow) and check it
-    is a loadable Chrome trace with driver spans in it."""
-    tpch_driver.query("q6")
-    path = tpch_driver.obs.save_chrome_trace(
-        "experiments/trace/sample_trace.json")
-    doc = json.loads(open(path).read())
-    names = {e["name"] for e in doc["traceEvents"]}
-    assert "query" in names and "route" in names
+    """Write the CI trace artifact (uploaded by the workflow): a profiler
+    trace of one query whose Perfetto JSON holds the driver's spans."""
+    tpch_driver.query("q6")  # compiled before the trace
+    log_dir = os.path.join("experiments", "trace")
+    shutil.rmtree(log_dir, ignore_errors=True)
+    with jax.profiler.trace(log_dir, create_perfetto_trace=True):
+        tpch_driver.query("q6")
+    names = {"query", "route", "bind", "dispatch", "fetch"}
+    events = _chrome_events(log_dir, names)
+    assert set(events) == names
     assert all(set(e) >= {"name", "ph", "ts", "pid", "tid"}
-               for e in doc["traceEvents"])
+               for evs in events.values() for e in evs)
+    (q,) = events["query"]
+    assert q["args"]["source"] == "q6" and int(q["args"]["request"]) >= 1
+
+
+def test_profiler_trace_holds_driver_spans(tpch_driver, tmp_path):
+    prep = tpch_driver.prepare("q6")
+    prep.execute()  # warm: the traced execution only dispatches
+    with jax.profiler.trace(str(tmp_path)):
+        prep.execute()
+    spans = _host_spans(tmp_path, {"query", "bind", "dispatch", "fetch"})
+    (q0, q1) = spans["query"][0]
+    assert len(spans["query"]) == 1
+    parts = []
+    for name in ("bind", "dispatch", "fetch"):
+        (part,) = spans[name]
+        assert q0 <= part[0] <= part[1] <= q1, name
+        parts.append(part)
+    (b, d, f) = parts
+    assert b[1] <= d[0] and d[1] <= f[0]  # in that order, disjoint
+    # the span tree holds the same split, numbered per request
+    last = tpch_driver.obs.last("query")
+    assert [c.name for c in last.children] == ["route", "bind", "dispatch",
+                                               "fetch"]
+    assert last.attrs["request"] > 1
+
+
+# filters no other test prepares, so the plan cache misses and the first
+# execution compiles
+FRESH_FILTERS = {
+    "execute": (C("l_quantity") < 9.0) & (C("l_tax") <= 0.07),
+    "execute_batch": (C("l_discount") >= 0.02) & (C("l_tax") < 0.05),
+}
+
+
+@pytest.mark.parametrize("how", sorted(FRESH_FILTERS))
+def test_load_seconds_times_setup_steps(tpch_driver, how):
+    ls = tpch_driver.load_seconds
+    assert {"generate", "pack", "place", "catalog"} <= set(ls)
+    assert all(v >= 0.0 for v in ls.values())
+    prep = tpch_driver.prepare(
+        Q.scan("lineitem").filter(FRESH_FILTERS[how])
+        .group_agg(keys=(), aggs=[("obs_tax", "sum", C("l_tax"))])
+        .named(f"obs_{how}"))
+    run = (prep.execute if how == "execute"
+           else lambda: prep.execute_batch([prep.defaults] * 2))
+    before = ls.get("compile", 0.0)
+    run()
+    first = ls["compile"]
+    assert first > before  # lowered, traced and compiled: set-up
+    run()
+    assert ls["compile"] == first  # a warm execution adds nothing
+
+
+LAYERS = ("scan", "semijoin", "aggregate", "topk")
+# HLO instruction: "[ROOT] %name = <shape> opcode(operands), ..."
+HLO_OPCODE = re.compile(r"^\s*(?:ROOT\s+)?%\S+\s*=\s*.*?\s([a-z][a-z0-9_\-]*)\(")
+
+
+def _compiled_hlo(driver, entry) -> str:
+    return driver._lowered(entry).compile().as_text()
+
+
+def _opcodes(hlo: str) -> collections.Counter:
+    return collections.Counter(m.group(1) for m in map(HLO_OPCODE.match,
+                                                        hlo.splitlines())
+                               if m)
+
+
+@pytest.mark.parametrize("name,want", [
+    ("q1", {"scan", "aggregate"}),
+    ("q6", {"scan", "aggregate"}),
+    ("q14_promo", {"scan", "semijoin", "aggregate"}),
+    ("q4", {"scan", "semijoin", "aggregate"}),
+    ("q18", {"aggregate", "scan", "topk"}),
+])
+def test_lowered_plans_carry_layer_scopes(tpch_driver, monkeypatch, name,
+                                          want):
+    entry = tpch_driver.prepare(name).entry
+    hlo = _compiled_hlo(tpch_driver, entry)
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', hlo):
+        scoped = [p for p in op_name.split("/") if p in LAYERS]
+        assert len(scoped) <= 1, op_name  # layers never nest
+        found.update(scoped)
+    assert found == want
+    # the scopes are metadata only: the same program without them
+    lower_mod = importlib.import_module("repro.query.lower")
+    monkeypatch.setattr(lower_mod, "layer",
+                        lambda layer_name: contextlib.nullcontext())
+    bare = _compiled_hlo(tpch_driver,
+                         _PlanEntry(entry.shape, entry.stats_binding))
+    assert not any(p in LAYERS for op_name in
+                   re.findall(r'op_name="([^"]*)"', bare)
+                   for p in op_name.split("/"))
+    assert _opcodes(bare) == _opcodes(hlo) and sum(_opcodes(hlo).values())
 
 
 def test_semijoin_info_describes_roofline_prediction():
