@@ -48,6 +48,7 @@ from repro.kernels import ops
 from repro.launch.roofline import parse_collective_bytes
 from repro.query.lower import lower
 from repro.tpch import queries as tq
+from repro.tpch import reference
 from repro.tpch.driver import TPCHDriver
 from repro.tpch.schema import DEFAULT_PARAMS as DP
 
@@ -76,16 +77,6 @@ def _clock(fn, cols) -> float:
     t0 = time.perf_counter()
     jax.block_until_ready(fn(cols))
     return time.perf_counter() - t0
-
-
-def _q18_sj_oracle(driver, qty: float, segment: int):
-    o = driver.tables["orders"].columns
-    li = driver.tables["lineitem"].columns
-    c = driver.tables["customer"].columns
-    sq = np.zeros(o["o_orderkey"].shape[0])
-    np.add.at(sq, li["l_orderkey"], li["l_quantity"].astype(np.float64))
-    sel = (sq > qty) & (c["c_mktsegment"][o["o_custkey"]] == segment)
-    return np.array([sq[sel].sum(), sel.sum()])
 
 
 def codec_microbench(repeat: int = 20, capacity: int = 4096, seed: int = 0):
@@ -145,7 +136,7 @@ def run(sf: float = 0.02, repeat: int = 30, seed: int = 0):
          np.asarray(driver.oracle("q4"), np.float64),
          lambda out: np.asarray(out["value"], np.float64)[:, 0]),
         ("q18_sj", tq.q18_sj_ir(alt="request", qty=SJ_QTY),
-         _q18_sj_oracle(driver, SJ_QTY, DP.q3_segment),
+         reference.q18_sj(driver.tables, SJ_QTY, DP.q3_segment),
          lambda out: np.asarray(out["value"], np.float64).reshape(-1)),
     ]
 

@@ -108,6 +108,27 @@ def group_sum_dense(values, keys, num_keys: int, mask=None):
     return jnp.zeros(num_keys, jnp.float32).at[keys].add(v)
 
 
+def blocks_table(child: str) -> str:
+    """Name of the resident table that holds a clustered ``child``'s block
+    starts (``clustered_sum.block_starts``, one ``first_row`` column,
+    partitioned like the parent)."""
+    return f"{child}_blocks"
+
+
+def group_sum_clustered(values, keys, tables, child: str, num_keys: int,
+                        fanout: int):
+    """``group_sum_dense`` of the rows of ``child``, which is clustered by
+    its foreign key ``keys``: non-decreasing, at most ``fanout`` rows per
+    key.  Reads the child's block starts from ``tables`` (table name ->
+    columns).  A segmented reduction over contiguous runs (the paper's
+    co-partitioned, clustered storage, §3.1): no random write, no sort."""
+    from repro.kernels import ops
+
+    return ops.clustered_sum(values.astype(jnp.float32), keys,
+                             tables[blocks_table(child)]["first_row"],
+                             num_keys=num_keys, fanout=fanout)
+
+
 def group_count_dense(keys, num_keys: int, mask=None):
     ones = jnp.ones(keys.shape[0], jnp.float32)
     return group_sum_dense(ones, keys, num_keys, mask)

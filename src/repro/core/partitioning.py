@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 
@@ -63,3 +64,22 @@ def copartitioned(parent: RangePartitioning, child_fanout: int) -> RangePartitio
     For variable fanout (lineitem per order) the generator pads to a fixed
     per-node row count instead and this helper is not used."""
     return RangePartitioning(parent.total_rows * child_fanout, parent.num_nodes)
+
+
+def clustered_fanout(fk, parent_rows: int, num_nodes: int) -> int:
+    """Longest run of one key in a child's foreign-key column, or 0 unless
+    the child is clustered by it: on every node the keys never decrease
+    and lie in that node's parent partition (``parent_rows`` per node).
+    ``fk`` holds global keys, node-major, as the generator lays out
+    lineitem (``np.repeat`` of its orders)."""
+    fk = np.asarray(fk)
+    if fk.size == 0 or fk.size % num_nodes or parent_rows <= 0:
+        return 0
+    per_node = fk.reshape(num_nodes, -1)
+    base = np.arange(num_nodes) * parent_rows
+    if ((np.diff(per_node, axis=1) < 0).any()
+            or (per_node[:, 0] < base).any()
+            or (per_node[:, -1] >= base + parent_rows).any()):
+        return 0
+    return int(np.bincount(fk).max())
+

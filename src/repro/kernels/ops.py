@@ -20,6 +20,7 @@ from repro.kernels import (
     topk_select,
     wire_codec,
 )
+from repro.kernels import clustered_sum as clustered_sum_kernel
 from repro.kernels import scan_filter as scan_filter_kernel
 
 _FORCE_REF = os.environ.get("REPRO_NO_KERNELS", "0") == "1"
@@ -41,6 +42,16 @@ def filtered_group_sum(measures, groups, pred, *, cutoff, num_groups, block=2048
         return ref.filtered_group_sum(measures, groups, pred, cutoff, num_groups)
     return grouped_agg.filtered_group_sum(
         measures, groups, pred, cutoff, num_groups, block=block,
+        interpret=_interpret(),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("num_keys", "fanout"))
+def clustered_sum(values, keys, block_starts, *, num_keys, fanout):
+    if not _USE_KERNELS:
+        return ref.clustered_sum(values, keys, num_keys)
+    return clustered_sum_kernel.clustered_sum(
+        values, keys, block_starts, num_keys=num_keys, fanout=fanout,
         interpret=_interpret(),
     )
 

@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.core import compression
+from repro.core.aggregation import group_sum_dense
 
 
 def filtered_group_sum(measures, groups, pred, cutoff, num_groups):
@@ -16,6 +17,10 @@ def filtered_group_sum(measures, groups, pred, cutoff, num_groups):
         measures.astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )
+
+
+def clustered_sum(values, keys, num_keys):
+    return group_sum_dense(values, keys, num_keys)
 
 
 def block_topk(values, keys, k, mask=None, block: int = 4096):

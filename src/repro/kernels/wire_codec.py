@@ -59,10 +59,11 @@ def _popcount(x):
 # widths: the validity mask packs 32 one-bit fields per word, the EF lower
 # bits 32/l l-bit fields.  The kernels see the fields as an (N, k) array,
 # one word per row, and tile N in row blocks: a block is (br, k) with k the
-# full minor dim, so every block shape the TPU lowering accepts.  Packing is
-# a static unrolled OR of shifted one-lane slices (disjoint bits, so OR ==
-# the reference's sum) — no reduction over unsigned integers, which Mosaic
-# does not lower.
+# full minor dim, so every block shape the TPU lowering accepts.  Packing
+# shifts each field to its place and sums the row in int32 (disjoint bits,
+# so the wrapping sum is the OR): Mosaic lowers no reduction over unsigned
+# integers, and an OR of shifted one-lane slices came out wrong on a v5e
+# for blocks of more than 8 rows (right in interpret mode).
 # ---------------------------------------------------------------------------
 
 _BLOCK_WORDS = 1024  # word rows per grid step (multiple of 8)
@@ -70,10 +71,10 @@ _BLOCK_WORDS = 1024  # word rows per grid step (multiple of 8)
 
 def _pack_kernel(x_ref, out_ref, *, shift):
     x = x_ref[...]                                # (br, k) uint32 fields
-    out = x[:, 0:1]
-    for j in range(1, x.shape[1]):
-        out = out | (x[:, j:j + 1] << jnp.uint32(j * shift))
-    out_ref[...] = out
+    sh = jax.lax.broadcasted_iota(jnp.uint32, x.shape, 1) * jnp.uint32(shift)
+    y = jax.lax.bitcast_convert_type(x << sh, jnp.int32)
+    out_ref[...] = jax.lax.bitcast_convert_type(
+        jnp.sum(y, axis=1, keepdims=True), jnp.uint32)
 
 
 def _unpack_kernel(w_ref, out_ref, *, shift):

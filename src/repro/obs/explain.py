@@ -171,9 +171,10 @@ class ExplainReport:
                     body += f" {info.describe()}"
                 body += "]"
             elif op == "Exists":
-                body = f"Exists[{row['table']} sel={row['sel']:.3g}]"
+                body = (f"Exists[{row['table']} sel={row['sel']:.3g} "
+                        f"path={row['path']}]")
             elif op == "GroupAggByKey":
-                body = f"GroupAggByKey[into={row['into']}]"
+                body = f"GroupAggByKey[into={row['into']} path={row['path']}]"
             elif op == "GroupAgg":
                 body = (f"GroupAgg[groups={row['groups']} "
                         f"method={row['method']} "

@@ -29,6 +29,8 @@ from typing import Mapping, Optional, Sequence
 
 import numpy as np
 
+from repro.core.partitioning import clustered_fanout
+
 
 # ---------------------------------------------------------------------------
 # typed errors (the satellite contract: never a bare KeyError/TypeError)
@@ -624,6 +626,10 @@ class Catalog:
     copartitioned: Mapping[str, tuple]
     num_nodes: int = 1
     device_kind: str = "cpu"  # jax device_kind the plans run on
+    # co-partitioned children clustered by their foreign key, observed in
+    # the data: child table -> longest run of one key (its largest fanout);
+    # keyed reductions into the parent take the clustered path
+    clustered: Mapping[str, int] = dataclasses.field(default_factory=dict)
 
     def table(self, name: str) -> TableInfo:
         try:
@@ -644,7 +650,9 @@ def build_catalog(tables: Mapping[str, object], *, num_nodes: int = 1,
     the resident encoding per table/column (the driver derives it from
     the packed resident tables) — the lowering and the SCAN001 verifier
     rule key off it.  ``device_kind`` names the device the plans will run
-    on; the lowering takes its scan-roofline rates from it."""
+    on; the lowering takes its scan-roofline rates from it.  Each
+    co-partitioned child whose foreign key is clustered on every node is
+    recorded in ``clustered`` (``partitioning.clustered_fanout``)."""
     infos = {}
     for name, t in tables.items():
         stats = {}
@@ -668,12 +676,23 @@ def build_catalog(tables: Mapping[str, object], *, num_nodes: int = 1,
             stats=stats,
             packed=dict((packed or {}).get(name, {})),
         )
+    edges = dict(TPCH_COPARTITIONED if copartitioned is None
+                 else copartitioned)
+    clustered = {}
+    for child, (parent, fk) in edges.items():
+        if child not in tables or parent not in tables:
+            continue
+        fanout = clustered_fanout(tables[child].columns[fk],
+                                  infos[parent].num_rows // num_nodes,
+                                  num_nodes)
+        if fanout:
+            clustered[child] = fanout
     return Catalog(
         tables=infos,
-        copartitioned=dict(TPCH_COPARTITIONED if copartitioned is None
-                           else copartitioned),
+        copartitioned=edges,
         num_nodes=num_nodes,
         device_kind=device_kind,
+        clustered=clustered,
     )
 
 

@@ -14,8 +14,11 @@ Physical mapping:
   Alt-1 (index-lookup request exchange) or Alt-2 (replicated bitset),
   chosen by the §3.2.2 cost model; exchange buffer capacities come from the
   selectivity model (``repro.query.stats``), not hand knobs
-- ``Exists``               -> co-partitioned scatter probe
-- ``GroupAggByKey``        -> dense scatter-add over the parent partition
+- ``Exists``, ``GroupAggByKey`` -> keyed reduction of the co-partitioned
+  child into the parent partition: a segmented sum over contiguous runs
+  where the catalog finds the child clustered by its foreign key
+  (``Catalog.clustered``), else a scatter (``.at[].max`` / ``.at[].add``);
+  the path of each is ``plan.keyed``
 - ``GroupAgg``             -> one-hot MXU contraction / dense scatter-add /
   the fused Pallas ``grouped_agg`` kernel, merged with one ``psum``
 - ``TopK``                 -> per-node top-k + §3.2.3 merging reduction,
@@ -255,6 +258,30 @@ def _decide_scans(root, catalog: Catalog) -> dict:
     return decisions
 
 
+def _decide_keyed(root, catalog: Catalog) -> dict:
+    """The path of each keyed reduction into a parent (``Exists``,
+    ``GroupAggByKey``): ``"clustered"`` where the catalog finds the child
+    clustered by its foreign key and the key is the stored column (no
+    projection below redefines it), else ``"scatter"``.  Returns
+    ``{id(node): path}`` in chain order."""
+    paths = {}
+    base, projected = None, set()
+    for node in _chain(root):
+        if isinstance(node, Scan):
+            base, projected = node.table, set()
+        elif isinstance(node, Project):
+            projected.update(name for name, _ in node.cols)
+        elif isinstance(node, Exists):
+            paths[id(node)] = ("clustered" if catalog.clustered.get(node.table)
+                               else "scatter")
+        elif isinstance(node, GroupAggByKey):
+            paths[id(node)] = (
+                "clustered" if catalog.clustered.get(base)
+                and node.key.name not in projected else "scatter")
+            base, projected = node.into, set()
+    return paths
+
+
 # stable public entry points for the static verifier (repro.query.verify):
 # the same decision passes the lowering runs, usable without lowering
 decide_semijoins = _decide_semijoins
@@ -276,6 +303,7 @@ def explain_chain(query: Query, catalog: Catalog, *, wire: str = "packed",
                                   wire=wire, binding=binding, cal=cal,
                                   predict_cal=predict_cal)
     scan_plans = _decide_scans(root, catalog)
+    keyed = _decide_keyed(root, catalog)
     rows = []
     base, sel = None, 1.0
     for node in _chain(root):
@@ -310,11 +338,13 @@ def explain_chain(query: Query, catalog: Catalog, *, wire: str = "packed",
         elif isinstance(node, Exists):
             sel *= qstats.DEFAULT_SELECTIVITY
             rows.append({"op": "Exists", "table": node.table,
-                         "sel": qstats.DEFAULT_SELECTIVITY, "cum_sel": sel})
+                         "sel": qstats.DEFAULT_SELECTIVITY, "cum_sel": sel,
+                         "path": keyed[id(node)]})
         elif isinstance(node, GroupAggByKey):
             base, sel = node.into, 1.0
             rows.append({"op": "GroupAggByKey", "into": node.into,
-                         "aggs": [a.name for a in node.aggs]})
+                         "aggs": [a.name for a in node.aggs],
+                         "path": keyed[id(node)]})
         elif isinstance(node, GroupAgg):
             groups = math.prod(k.cardinality for k in node.keys) \
                 if node.keys else 1
@@ -502,6 +532,7 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
     sj_plans = _decide_semijoins(root, catalog, query_name=query.name,
                                  wire=wire, binding=binding)
     scan_plans = _decide_scans(root, catalog)
+    keyed = _decide_keyed(root, catalog)
     # the mask-GEMM only pays where the cluster vmaps batch lanes; larger
     # partitions run them one by one (Cluster.compile), where the plain
     # lowering is the same work
@@ -515,6 +546,7 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
             n_params=len(params),
             semijoins=" ".join(f"{d.key}:{d.alt}" for d in sj_plans.values())
             or "none",
+            keyed=" ".join(keyed.values()) or "none",
         )
 
     def _eval(node, ctx, t, pv) -> _Stream:
@@ -613,7 +645,12 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
             bits = eval_expr(node.pred, inner, pv)
             rows = ctx.part(s.base).rows_per_node
             fk_local = _local_index(ctx, s.base, inner[node.key])
-            has = jnp.zeros(rows, bool).at[fk_local].max(bits)
+            if keyed[id(node)] == "clustered":
+                has = aggregation.group_sum_clustered(
+                    bits, fk_local, t, node.table, rows,
+                    catalog.clustered[node.table]) > 0
+            else:
+                has = jnp.zeros(rows, bool).at[fk_local].max(bits)
             s.and_mask(has)
             return s
 
@@ -630,7 +667,11 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                     v = eval_expr(a.expr, s.cols, pv).astype(jnp.float32)
                 if s.mask is not None:
                     v = jnp.where(s.mask, v, 0.0)
-                derived[a.name] = jnp.zeros(rows, jnp.float32).at[idx].add(v)
+                if keyed[id(node)] == "clustered":
+                    derived[a.name] = aggregation.group_sum_clustered(
+                        v, idx, t, s.base, rows, catalog.clustered[s.base])
+                else:
+                    derived[a.name] = aggregation.group_sum_dense(v, idx, rows)
             cols = _LazyCols(t[node.into])
             cols.update(derived)
             return _Stream(base=node.into, cols=cols, mask=None,
@@ -757,6 +798,9 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
     # storage.bytes_scanned accounting and EXPLAIN read these
     plan.scans = tuple(d for per in scan_plans.values()
                        for _, ds in per for d in ds)
+    # the path of each keyed reduction into a parent (chain order): the
+    # driver's plan.keyed.* counters and EXPLAIN read these
+    plan.keyed = tuple(keyed.values())
     # lowered plans consume packed-resident columns directly (lazy decode,
     # predicate-on-packed, gather-based late materialization) — the engine
     # must NOT expand them at entry

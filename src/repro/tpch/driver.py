@@ -47,6 +47,8 @@ from repro.core import Cluster, Table
 from repro.core import plans as plan_registry
 from repro.core import wirecal
 from repro.core.columnar import PackedColumn
+from repro.core.aggregation import blocks_table
+from repro.kernels import clustered_sum
 from repro.query.ir import PackedInfo
 from repro.cube import CubeRouter, build_cube
 from repro.obs import (
@@ -460,6 +462,7 @@ class TPCHDriver:
                                          num_nodes=self.cluster.num_nodes,
                                          packed=packed_meta,
                                          device_kind=self.cluster.device_kind)
+            self._add_cluster_blocks()
         # resident-footprint accounting + node memory budget: the budget
         # models per-node main memory; exceeding it is the OOM the packed
         # format exists to push out by ~the compression ratio
@@ -522,6 +525,23 @@ class TPCHDriver:
             {"c_mktsegment": np.asarray(cust.columns["c_mktsegment"])},
             replicated=True,
         )
+
+    def _add_cluster_blocks(self):
+        """Resident block starts of every child the catalog finds clustered
+        by its foreign key (read by the lowering's clustered keyed
+        reductions), partitioned like the parent.  Placed for every such
+        child, whether or not a prepared query reduces it: the clustering
+        fact holds for any ``Query`` the driver is later given, and the
+        starts are small (partsupp's about 62 KB at SF 10, lineitem's
+        0.5 MB)."""
+        nodes = self.cluster.num_nodes
+        for child in self.catalog.clustered:
+            parent, fk = self.catalog.copartitioned[child]
+            starts = clustered_sum.block_starts(
+                self.tables[child].columns[fk],
+                self.tables[parent].num_rows // nodes, nodes)
+            name = blocks_table(child)
+            self.resident[name] = Table(name, {"first_row": starts})
 
     def _columns(self):
         return {n: t.columns for n, t in self.placed.items()}
@@ -683,6 +703,8 @@ class TPCHDriver:
                      obs=self.obs)
         entry.semijoins = tuple(getattr(plan, "semijoins", ()))
         entry.scans = tuple(getattr(plan, "scans", ()))
+        for path in plan.keyed:
+            self.obs.metrics.counter(f"plan.keyed.{path}").inc()
         events = self.compile_events
         obs = self.obs
         drv = self

@@ -194,6 +194,18 @@ def q18(t, p=DP, k=100):
     )
 
 
+def q18_sj(t, qty=250.0, segment=DP.q3_segment):
+    """``queries.q18_sj_ir``: total quantity and count of the orders above
+    ``qty`` whose customer is in market ``segment``."""
+    li = t["lineitem"].columns
+    orders = t["orders"].columns
+    cust = t["customer"].columns
+    sq = np.zeros(orders["o_orderkey"].shape[0])
+    np.add.at(sq, li["l_orderkey"], li["l_quantity"].astype(np.float64))
+    sel = (sq > qty) & (cust["c_mktsegment"][orders["o_custkey"]] == segment)
+    return np.array([sq[sel].sum(), sel.sum()])
+
+
 def q21(t, p=DP, k=100):
     li = t["lineitem"].columns
     orders = t["orders"].columns

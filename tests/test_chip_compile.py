@@ -21,11 +21,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import compression
-from repro.kernels import grouped_agg, scan_filter, wire_codec
+from repro.kernels import clustered_sum, grouped_agg, scan_filter, wire_codec
 
 pytestmark = pytest.mark.tier1
 
 LINEITEM_ROWS = 60_000_000    # SF 10 lineitem on one chip
+ORDERS_ROWS = 15_000_000      # SF 10 orders on one chip
 PART_ROWS = 2_000_000         # SF 10 part: the exchange key domain
 CAPACITY = 1 << 18            # keys per destination of a request exchange
 
@@ -78,6 +79,35 @@ def test_filtered_group_sum_compiles(one_chip):
 
     text = _compiled_text(agg, one_chip, ((n, 8), jnp.float32),
                           ((n,), jnp.int32), ((n,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_clustered_sum_compiles(one_chip):
+    """q18's group-by-key and q4's EXISTS: lineitem into orders, longest
+    run 7, block starts scalar-prefetched over two calls."""
+    def reduce(values, keys, starts):
+        return clustered_sum.clustered_sum(values, keys, starts,
+                                           num_keys=ORDERS_ROWS, fanout=7)
+
+    blocks = -(-ORDERS_ROWS // clustered_sum.BLOCK)
+    text = _compiled_text(reduce, one_chip,
+                          ((LINEITEM_ROWS,), jnp.float32),
+                          ((LINEITEM_ROWS,), jnp.int32), ((blocks,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_clustered_sum_compiles_vmapped(one_chip):
+    """The batched lowering vmaps the kernel over lanes whose values differ
+    (``q4_sj``'s per-lane semi-join mask) while keys and block starts are
+    shared: the batching rule adds a grid axis over the lanes."""
+    rows, orders = 1 << 22, 1 << 20   # the largest vmapped partition
+    def reduce(values, keys, starts):
+        return jax.vmap(lambda v: clustered_sum.clustered_sum(
+            v, keys, starts, num_keys=orders, fanout=7))(values)
+
+    blocks = -(-orders // clustered_sum.BLOCK)
+    text = _compiled_text(reduce, one_chip, ((4, rows), jnp.float32),
+                          ((rows,), jnp.int32), ((blocks,), jnp.int32))
     assert "tpu_custom_call" in text
 
 
