@@ -146,12 +146,14 @@ def required_width(max_val: int) -> int:
 
 def pack_bitset(bits):
     """bool[n] -> uint32[ceil(n/32)] (n must be a multiple of 32 for the
-    engine's fixed shapes; callers pad)."""
+    engine's fixed shapes; callers pad).  Bit ``j`` of every word is one
+    strided slice: the ``(n/32, 32)`` form compiles to code that grows
+    with ``n`` on a TPU (112 s for 60M bits, against 3 s)."""
     n = bits.shape[0]
     assert n % 32 == 0, f"bitset length must be multiple of 32, got {n}"
-    b = bits.reshape(n // 32, 32).astype(jnp.uint32)
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))[None, :]
-    return jnp.sum(b * weights, axis=1, dtype=jnp.uint32)
+    b = bits.astype(jnp.uint32)
+    return sum(lax.slice(b, (j,), (n - 31 + j,), (32,)) << j
+               for j in range(32))
 
 
 def unpack_bitset(words, n: int):

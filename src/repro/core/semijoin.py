@@ -10,6 +10,10 @@ Alt-2 (bitset): owners evaluate the predicate over their whole partition and
 allgather the resulting bitset (packed, so the volume is visible in HLO);
 every node then probes locally.  ~γm·log2(1/γ) bits.
 
+A local probe after a filter (Alt-2's, or a co-partitioned one) touches
+only the rows the filter keeps: :func:`probe_survivors` compacts their
+row indices on the device and probes those alone.
+
 ``choose_alternative`` applies the paper's cost model; the plans pin the
 choice the paper made per query and the benchmark sweeps both.
 """
@@ -82,6 +86,57 @@ def alt2_bitset(
         local_bits = jnp.concatenate([local_bits, jnp.zeros(pad, bool)])
     packed = compression.pack_bitset(local_bits)
     return lax.all_gather(packed, axis, tiled=True)
+
+
+# The compacted probe's capacities, as fractions of a node's rows.
+PROBE_FRACTIONS = (64, 16)
+FULL_WIDTH = len(PROBE_FRACTIONS)   # the tier of the probe over every row
+
+
+def probe_capacities(rows: int) -> tuple:
+    """The slot counts of the compacted probe over a ``rows``-row
+    partition: for each fraction ``f``, the power of two at or above
+    ``rows / f``."""
+    return tuple(1 << max(-(-rows // f) - 1, 0).bit_length()
+                 for f in PROBE_FRACTIONS)
+
+
+def probe_survivors(mask, words, keys, test):
+    """``test(keys(None)) & mask``, probing only the rows ``mask`` keeps.
+
+    ``words`` is the mask's bitset (``compression.pack_bitset`` layout) or
+    None; ``keys(idx)`` gives the join keys of the node-local rows ``idx``
+    (every row's for ``None``) and ``test(keys)`` the semi-join's bits for
+    them.  The survivors' row indices are compacted on the device
+    (``kernels/compact.py``) into the smallest of :func:`probe_capacities`
+    that holds them, and the bits of the hits go back into a bitset by a
+    scatter of that many slots.  Where the survivors fill the largest
+    capacity every row is probed.  Returns ``(bits, tier)``: the index of
+    the capacity taken, ``FULL_WIDTH`` for every row."""
+    from repro.kernels import compact, ops
+
+    rows = mask.shape[0]
+    if words is None:
+        words = compression.pack_bitset(jnp.pad(mask, (0, (-rows) % 32)))
+    offsets = compact.plane_offsets(words)
+    caps = probe_capacities(rows)
+    tier = sum((offsets[-1] > cap).astype(jnp.int32) for cap in caps)
+
+    def compacted(cap):
+        def run():
+            idx = ops.compact_rows(words, offsets, cap=cap)
+            kept = jnp.arange(cap) < offsets[-1]
+            hit = test(keys(jnp.where(kept, idx, 0))) & kept
+            bit = jnp.uint32(1) << (idx & 31).astype(jnp.uint32)
+            hits = jnp.zeros_like(words).at[
+                jnp.where(hit, idx >> 5, words.shape[0])].add(bit, mode="drop")
+            return compression.unpack_bitset(hits, rows)
+        return run
+
+    def every_row():
+        return test(keys(None)) & mask
+
+    return lax.switch(tier, [compacted(c) for c in caps] + [every_row]), tier
 
 
 def probe(global_bitset_words, keys, part: RangePartitioning):

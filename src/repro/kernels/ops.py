@@ -21,6 +21,7 @@ from repro.kernels import (
     wire_codec,
 )
 from repro.kernels import clustered_sum as clustered_sum_kernel
+from repro.kernels import compact as compact_kernel
 from repro.kernels import scan_filter as scan_filter_kernel
 
 _FORCE_REF = os.environ.get("REPRO_NO_KERNELS", "0") == "1"
@@ -54,6 +55,17 @@ def clustered_sum(values, keys, block_starts, *, num_keys, fanout):
         values, keys, block_starts, num_keys=num_keys, fanout=fanout,
         interpret=_interpret(),
     )
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))
+def compact_rows(words, offsets, *, cap):
+    """The first ``cap`` row indices of the set bits of a packed mask, in
+    the kernel's slot order; slots past them are undefined (``offsets``:
+    ``compact.plane_offsets`` of the same words)."""
+    if not _USE_KERNELS:
+        return ref.compact_rows(words, cap)
+    return compact_kernel.compact_rows(words, offsets, cap=cap,
+                                       interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block"))

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 
 from repro.core import compression
 from repro.core.aggregation import group_sum_dense
+from repro.kernels.compact import plane_order
 
 
 def filtered_group_sum(measures, groups, pred, cutoff, num_groups):
@@ -21,6 +22,16 @@ def filtered_group_sum(measures, groups, pred, cutoff, num_groups):
 
 def clustered_sum(values, keys, num_keys):
     return group_sum_dense(values, keys, num_keys)
+
+
+def compact_rows(words, cap):
+    """Set bits of a packed mask in the compaction kernel's slot order;
+    -1 in the slots past them."""
+    order = plane_order(words.shape[0])
+    bits = compression.unpack_bitset(words, words.shape[0] * 32)
+    bits = jnp.pad(bits, (0, order.shape[0] - bits.shape[0]))[order]
+    (pos,) = jnp.nonzero(bits, size=cap, fill_value=-1)
+    return jnp.where(pos >= 0, order[pos], -1)
 
 
 def block_topk(values, keys, k, mask=None, block: int = 4096):

@@ -169,6 +169,8 @@ class ExplainReport:
                 body = f"SemiJoin[{row['table']} key={fmt_expr(row['key'])}"
                 if info is not None:
                     body += f" {info.describe()}"
+                if row.get("probe"):
+                    body += f" probe={row['probe']}"
                 body += "]"
             elif op == "Exists":
                 body = (f"Exists[{row['table']} sel={row['sel']:.3g} "

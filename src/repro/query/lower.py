@@ -13,7 +13,10 @@ Physical mapping:
 - ``SemiJoin``             -> local probe for co-partitioned edges, else
   Alt-1 (index-lookup request exchange) or Alt-2 (replicated bitset),
   chosen by the §3.2.2 cost model; exchange buffer capacities come from the
-  selectivity model (``repro.query.stats``), not hand knobs
+  selectivity model (``repro.query.stats``), not hand knobs.  A local or
+  bitset probe after a filter probes only the rows the filter keeps
+  (``semijoin.probe_survivors``), unless the plan vmaps its lanes; the
+  path of each is ``plan.probes``
 - ``Exists``, ``GroupAggByKey`` -> keyed reduction of the co-partitioned
   child into the parent partition: a segmented sum over contiguous runs
   where the catalog finds the child clustered by its foreign key
@@ -41,6 +44,9 @@ time.  The result is then incomplete; recover by re-compiling with an
 explicit capacity override in ``PlanContext.capacities`` under the key
 ``"<query-name>_sj<i>"`` (the i-th request semijoin of the chain) — for
 ``TPCHDriver``, pass it via the ``capacities=`` constructor argument.
+A plan with compacted probes also returns ``"probe_tier"``: for each, the
+index of the capacity its survivors took, ``semijoin.FULL_WIDTH`` where
+they filled every capacity and every row was probed (not an overflow).
 
 Min/max aggregates are Tier-1-only (rollup cubes serve them); lowering
 them raises :class:`LoweringError`.
@@ -258,6 +264,26 @@ def _decide_scans(root, catalog: Catalog) -> dict:
     return decisions
 
 
+def _decide_probes(root, sj_plans: dict, lanes_vmapped: bool) -> dict:
+    """The path of each local or bitset probe: ``"compact"`` where a
+    filter, semi-join or EXISTS has masked the stream before it (only the
+    rows it keeps are probed, ``semijoin.probe_survivors``), else
+    ``"full"``.  Plans that vmap their lanes probe every row: under
+    ``vmap`` the capacity switch would run every branch.  Returns
+    ``{id(node): path}`` in chain order."""
+    paths = {}
+    masked = False
+    for node in _chain(root):
+        if isinstance(node, (Scan, GroupAggByKey)):
+            masked = False
+        elif isinstance(node, SemiJoin) and sj_plans[id(node)].alt != "request":
+            paths[id(node)] = ("compact" if masked and not lanes_vmapped
+                               else "full")
+        if isinstance(node, (Filter, SemiJoin, Exists)):
+            masked = True
+    return paths
+
+
 def _decide_keyed(root, catalog: Catalog) -> dict:
     """The path of each keyed reduction into a parent (``Exists``,
     ``GroupAggByKey``): ``"clustered"`` where the catalog finds the child
@@ -304,6 +330,7 @@ def explain_chain(query: Query, catalog: Catalog, *, wire: str = "packed",
                                   predict_cal=predict_cal)
     scan_plans = _decide_scans(root, catalog)
     keyed = _decide_keyed(root, catalog)
+    probes = _decide_probes(root, decisions, lanes_vmapped=False)
     rows = []
     base, sel = None, 1.0
     for node in _chain(root):
@@ -333,7 +360,7 @@ def explain_chain(query: Query, catalog: Catalog, *, wire: str = "packed",
                 "pred": node.pred, "alt": d.alt, "capacity": d.capacity,
                 "capacity_key": d.key, "wire": d.wire, "gamma": d.gamma,
                 "codec_ms": d.codec_ms, "wire_ms": d.wire_ms,
-                "cum_sel": sel,
+                "cum_sel": sel, "probe": probes.get(id(node)),
             })
         elif isinstance(node, Exists):
             sel *= qstats.DEFAULT_SELECTIVITY
@@ -433,7 +460,10 @@ class _LazyCols(dict):
 
 def _col_at(col, idx):
     """Rows ``idx`` of a local column — code-space gather + decode for
-    packed residents (touches O(len(idx)) words, not the column)."""
+    packed residents (touches O(len(idx)) words, not the column); every
+    row for ``idx=None``."""
+    if idx is None:
+        return col.decode() if isinstance(col, PackedColumn) else col
     return col.gather(idx) if isinstance(col, PackedColumn) else col[idx]
 
 
@@ -443,9 +473,18 @@ class _Stream:
     cols: dict         # visible columns (local partition views)
     mask: object       # bool array or None
     overflow: object   # python False until an exchange contributes a flag
+    probe: tuple = ()  # the tier each compacted probe took
+    words: object = None  # the mask's bitset words, where a scan made them
 
-    def and_mask(self, bits):
-        self.mask = bits if self.mask is None else (self.mask & bits)
+    def and_mask(self, bits, words=None):
+        """AND ``bits`` into the mask; ``words`` is their bitset, where the
+        packed scan made it (kept while every part of the mask has one)."""
+        if self.mask is None:
+            self.mask, self.words = bits, words
+            return
+        self.mask = self.mask & bits
+        self.words = (None if words is None or self.words is None
+                      else self.words & words)
 
 
 def _local_index(ctx, table, keys):
@@ -539,6 +578,7 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
     lanes_vmapped = vmaps_lanes(
         max((t.num_rows for t in catalog.tables.values()
              if not t.replicated), default=0) // max(catalog.num_nodes, 1))
+    probes = _decide_probes(root, sj_plans, batched and lanes_vmapped)
     if obs is not None:
         obs.event(
             "lower", cat="plan",
@@ -547,6 +587,7 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
             semijoins=" ".join(f"{d.key}:{d.alt}" for d in sj_plans.values())
             or "none",
             keyed=" ".join(keyed.values()) or "none",
+            probe=" ".join(probes.values()) or "none",
         )
 
     def _eval(node, ctx, t, pv) -> _Stream:
@@ -591,7 +632,7 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                         s.and_mask(eval_expr(conj, s.cols, pv))
             if acc is not None:
                 rows, padded = acc_shape
-                s.and_mask(compression.unpack_bitset(acc, padded)[:rows])
+                s.and_mask(compression.unpack_bitset(acc, padded)[:rows], acc)
             return s
 
         if isinstance(node, Project):
@@ -603,15 +644,8 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
             plan = sj_plans[id(node)]
             target_cols = _LazyCols(t[node.table])
             part = ctx.part(node.table)
-            key = eval_expr(node.key, s.cols, pv)
-            if plan.alt == "local":
-                bits_owner = eval_expr(node.pred, target_cols, pv)
-                s.and_mask(bits_owner[_local_index(ctx, node.table, key)])
-            elif plan.alt == "bitset":
-                local_bits = eval_expr(node.pred, target_cols, pv)
-                words = semijoin.alt2_bitset(local_bits, axis=ctx.axis)
-                s.and_mask(semijoin.probe(words, key, part))
-            else:  # request (Alt-1 index-lookup exchange)
+            if plan.alt == "request":  # Alt-1 index-lookup exchange
+                key = eval_expr(node.key, s.cols, pv)
                 needed = expr_columns(node.pred)
 
                 def pred_fn(local_idx, m, _cols=target_cols, _p=node.pred,
@@ -638,6 +672,30 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                 )
                 s.and_mask(bits)
                 s.overflow = s.overflow | ovf
+                return s
+            if plan.alt == "local":
+                owner = eval_expr(node.pred, target_cols, pv)
+
+                def test(k, _t=node.table):
+                    return owner[_local_index(ctx, _t, k)]
+            else:  # bitset (Alt-2)
+                words = semijoin.alt2_bitset(
+                    eval_expr(node.pred, target_cols, pv), axis=ctx.axis)
+
+                def test(k):
+                    return semijoin.probe(words, k, part)
+            if probes[id(node)] == "full":
+                s.and_mask(test(eval_expr(node.key, s.cols, pv)))
+                return s
+            def keys(idx, _cols=s.cols, _k=node.key, _pv=pv):
+                # decoded here, not cached in the stream: a branch of the
+                # probe's capacity switch traces this
+                return eval_expr(_k, {c: _col_at(_cols.raw(c), idx)
+                                      for c in expr_columns(_k)}, _pv)
+
+            bits, tier = semijoin.probe_survivors(s.mask, s.words, keys, test)
+            s.and_mask(bits)
+            s.probe += (tier,)
             return s
 
         if isinstance(node, Exists):
@@ -675,7 +733,7 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
             cols = _LazyCols(t[node.into])
             cols.update(derived)
             return _Stream(base=node.into, cols=cols, mask=None,
-                           overflow=s.overflow)
+                           overflow=s.overflow, probe=s.probe)
 
     def _run(ctx, t, pv):
         s = _eval(root.child, ctx, t, pv)
@@ -738,9 +796,7 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                         axis=1,
                     )
             out = {"value": lax.psum(local, ctx.axis)}
-            if s.overflow is not False:
-                out["overflow"] = s.overflow
-            return out
+            return _flags(out, s)
 
         # TopK root
         if root.pred is not None:
@@ -768,8 +824,16 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
                 {f.name: t[f.table][f.name]}, axis=ctx.axis,
             )
             out.update(attrs)
+        return _flags(out, s)
+
+    def _flags(out, s: _Stream):
+        """The stream's exchange-overflow flag and the tier of each
+        compacted probe, beside the result (each as its node computed
+        it)."""
         if s.overflow is not False:
             out["overflow"] = s.overflow
+        if s.probe:
+            out["probe_tier"] = jnp.stack(s.probe)
         return out
 
     def _group_ids(node: GroupAgg, s: _Stream, pv, *, clip: bool):
@@ -801,6 +865,10 @@ def lower(query: Query, catalog: Catalog, *, wire: str = "packed",
     # the path of each keyed reduction into a parent (chain order): the
     # driver's plan.keyed.* counters and EXPLAIN read these
     plan.keyed = tuple(keyed.values())
+    # the path of each local or bitset probe (chain order): the driver's
+    # semijoin.probe.* counters and EXPLAIN read these; a "compact" probe
+    # also returns the tier it took as the plan's "probe_tier" output
+    plan.probes = tuple(probes.values())
     # lowered plans consume packed-resident columns directly (lazy decode,
     # predicate-on-packed, gather-based late materialization) — the engine
     # must NOT expand them at entry

@@ -45,7 +45,7 @@ import numpy as np
 
 from repro.core import Cluster, Table
 from repro.core import plans as plan_registry
-from repro.core import wirecal
+from repro.core import semijoin, wirecal
 from repro.core.columnar import PackedColumn
 from repro.core.aggregation import blocks_table
 from repro.kernels import clustered_sum
@@ -147,6 +147,7 @@ class _PlanEntry:
         self.route = (None, None)  # (router identity, Match|None) memo
         self.semijoins = ()     # static semi-join decisions of the lowering
         self.scans = ()         # static per-column scan strategies
+        self.probes = {}        # batched? -> path of each local/bitset probe
         self.profile = None     # lazy HLO CollectiveStats (explain_analyze)
         self.lock = threading.Lock()  # guards lazy compile + first trace
         self.warm = set()       # specializations already traced once
@@ -298,10 +299,12 @@ class PreparedQuery:
             with obs.span("fetch", cat="exec"):
                 out = jax.device_get(out)
             overflow = bool(np.asarray(out.pop("overflow", False)))
+            tiers = out.pop("probe_tier", None)
             value = out["value"] if set(out) == {"value"} else out
             sp.set(tier=2, route=self.source, overflow=overflow)
             mreg.counter("driver.tier2").inc()
             self.driver._count_scan_bytes(self.entry)
+            self.driver._count_probes(self.entry, False, tiers)
             if overflow:
                 mreg.counter("exchange.overflow").inc()
             mreg.histogram("query.tier2_us").record(
@@ -372,10 +375,13 @@ class PreparedQuery:
             overflow = out.pop("overflow", None)
             overflow = (np.zeros(lanes, bool) if overflow is None
                         else np.asarray(overflow))
+            tiers = out.pop("probe_tier", None)
             value = out["value"] if set(out) == {"value"} else out
             if lanes != B:  # drop the padding lanes from every output
                 value = jax.tree.map(lambda a: a[:B], value)
                 overflow = overflow[:B]
+            self.driver._count_probes(
+                self.entry, True, None if tiers is None else tiers[:B], B)
             n_ovf = int(np.asarray(overflow).sum())
             sp.set(tier=2, overflow_lanes=n_ovf)
             mreg.counter("driver.batch").inc()
@@ -577,6 +583,22 @@ class TPCHDriver:
             total += b
         mreg.counter("storage.bytes_scanned").inc(total)
 
+    def _count_probes(self, entry: _PlanEntry, batched: bool, tiers,
+                      lanes: int = 1) -> None:
+        """Count each local or bitset probe of an execution (of each lane
+        of a batch) in ``semijoin.probe.compact`` or ``.full``: a compacted
+        probe counts as full where its survivors filled every capacity
+        (``tiers``, the plan's ``probe_tier`` output)."""
+        paths = entry.probes.get(batched, ())
+        if not paths:
+            return
+        tiers = np.asarray(() if tiers is None else tiers).reshape(lanes, -1)
+        full = int((tiers >= semijoin.FULL_WIDTH).sum())
+        mreg = self.obs.metrics
+        mreg.counter("semijoin.probe.compact").inc(tiers.size - full)
+        mreg.counter("semijoin.probe.full").inc(
+            full + paths.count("full") * lanes)
+
     def _guarded_call(self, entry, key, fn, *args):
         """Run one device dispatch of ``entry``'s specialization ``key``.
 
@@ -703,6 +725,7 @@ class TPCHDriver:
                      obs=self.obs)
         entry.semijoins = tuple(getattr(plan, "semijoins", ()))
         entry.scans = tuple(getattr(plan, "scans", ()))
+        entry.probes[batched] = plan.probes
         for path in plan.keyed:
             self.obs.metrics.counter(f"plan.keyed.{path}").inc()
         events = self.compile_events

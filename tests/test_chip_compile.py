@@ -14,6 +14,7 @@ described.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -21,7 +22,13 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import compression
-from repro.kernels import clustered_sum, grouped_agg, scan_filter, wire_codec
+from repro.kernels import (
+    clustered_sum,
+    compact,
+    grouped_agg,
+    scan_filter,
+    wire_codec,
+)
 
 pytestmark = pytest.mark.tier1
 
@@ -32,10 +39,9 @@ CAPACITY = 1 << 18            # keys per destination of a request exchange
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     cache_was = jax.config.jax_enable_compilation_cache
@@ -47,8 +53,15 @@ def one_chip():
     except Exception as e:  # no TPU compiler in this installation
         jax.config.update("jax_enable_compilation_cache", cache_was)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compiled_text(fn, sharding, *shapes):
@@ -134,3 +147,89 @@ def test_wire_codec_compiles(one_chip, nodes, stage):
         words = compression.packed_request_words(CAPACITY, domain)
         shapes = (((nodes, words), jnp.uint32),)
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("cap", [1 << 20, 1 << 22])
+def test_compact_rows_compiles(one_chip, cap):
+    """q14_promo's survivors: 60M rows of mask words into 2^20 or 2^22
+    slots, the output held in VMEM over the whole grid."""
+    def survivors(words):
+        return compact.compact_rows(words, compact.plane_offsets(words),
+                                    cap=cap)
+
+    words = -(-LINEITEM_ROWS // 32)
+    assert "tpu_custom_call" in _compiled_text(survivors, one_chip,
+                                               ((words,), jnp.uint32))
+
+
+@pytest.fixture(scope="module")
+def q14_tables():
+    """Lineitem and part at SF 10 row counts, packed as the generator's
+    data at SF 0.01 packs them: the catalog and the packed column layouts,
+    with the SF 0.01 statistics."""
+    from repro.core.columnar import PackedColumn
+    from repro.query.ir import PackedInfo, build_catalog
+    from repro.tpch import dbgen
+
+    small = dbgen.generate(0.01, 1, 0)
+    small = {n: small[n] for n in ("lineitem", "part")}
+    packed = dbgen.pack_tables(small, 1)
+    meta = {n: {c: PackedInfo(width=col.width, offset=col.offset,
+                              values=col.values, dtype=col.dtype)
+                for c, col in t.columns.items()
+                if isinstance(col, PackedColumn)}
+            for n, t in packed.items()}
+    catalog = build_catalog(small, packed=meta, device_kind="TPU v5 lite")
+    return packed, catalog
+
+
+@pytest.mark.parametrize("nodes", [1, 4])
+@pytest.mark.parametrize("param", [False, True])
+def test_q14_promo_plan_compiles(topo, q14_tables, monkeypatch, nodes,
+                                 param):
+    """The whole q14_promo plan, literal and parameterized, with its probe
+    compacted, on one chip and as a four-node cluster (15M lineitem rows a
+    node): the scan and compaction kernels are in the program."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core import Cluster, Table
+    from repro.core.columnar import PackedColumn
+    from repro.kernels import ops
+    from repro.query.lower import lower
+    from repro.tpch import queries as tq
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    packed, catalog = q14_tables
+    cluster = Cluster(devices=topo.devices[:nodes])
+    part = NamedSharding(cluster.mesh, P(cluster.axis))
+    rows = {"lineitem": LINEITEM_ROWS, "part": PART_ROWS}
+    tables = {}
+    for name, t in packed.items():
+        local = rows[name] // nodes
+        padded = -(-local // 32) * 32
+        tables[name] = Table(name, {
+            c: (dataclasses.replace(
+                    col, rows=local, padded_rows=padded, num_nodes=nodes,
+                    words=jax.ShapeDtypeStruct(
+                        (nodes * compression.packed_words(padded, col.width),),
+                        jnp.uint32, sharding=part))
+                if isinstance(col, PackedColumn)
+                else jax.ShapeDtypeStruct((rows[name],), col.dtype,
+                                          sharding=part))
+            for c, col in t.columns.items()})
+    catalog = dataclasses.replace(
+        catalog, num_nodes=nodes,
+        tables={n: dataclasses.replace(i, num_rows=rows[n])
+                for n, i in catalog.tables.items()})
+    q = tq.q14_promo_param_ir() if param else tq.q14_promo_ir()
+    plan = lower(q, catalog)
+    assert plan.probes == ("compact",)
+    fn = cluster.compile(plan, cluster.context(tables), tables)
+    args = [{n: t.columns for n, t in tables.items()}]
+    if plan.params:
+        args.append({p.name: jax.ShapeDtypeStruct(
+            (), p.dtype, sharding=NamedSharding(cluster.mesh, P()))
+            for p in plan.params})
+    text = fn.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3   # scan + two capacities
